@@ -124,8 +124,8 @@ def _ragged_vs_padded(rec, iters, smoke):
     nblocks = B * n_mapped + 1
     k = jax.random.key(0)
     q = jax.random.normal(k, (B, 1, Hq, D), jnp.float32)
-    kp = jax.random.normal(k, (nblocks, bs, Hkv, D), jnp.float32)
-    vp = jax.random.normal(k, (nblocks, bs, Hkv, D), jnp.float32)
+    kp = jax.random.normal(k, (nblocks, Hkv, bs, D), jnp.float32)   # head-major
+    vp = jax.random.normal(k, (nblocks, Hkv, bs, D), jnp.float32)
     bt = np.zeros((B, nmax), np.int32)           # unmapped tail = null block
     bt[:, :n_mapped] = 1 + np.arange(B * n_mapped).reshape(B, n_mapped)
     lens = jnp.full((B,), ctx, jnp.int32)
@@ -137,8 +137,9 @@ def _ragged_vs_padded(rec, iters, smoke):
     from repro.kernels.ops import KernelConfig
     itp = KernelConfig("interpret")
     rag = lambda *a: ops.paged_ragged_attention(*a, kcfg=itp)  # noqa: E731
-    t_pad = _t(ops.paged_decode_attention, q, kp, vp, jnp.asarray(bt), lens,
-               iters=iters)
+    # the padded decode kernel reads the head-minor [nblocks, bs, Hkv, D]
+    t_pad = _t(ops.paged_decode_attention, q, kp.transpose(0, 2, 1, 3),
+               vp.transpose(0, 2, 1, 3), jnp.asarray(bt), lens, iters=iters)
     t_rag = _t(rag, q, kp, vp, jnp.asarray(bt), ones, lens, iters=iters)
     t_sli = _t(rag, q, kp, vp, sliced, ones, lens, iters=iters)
     # the production CPU fallback (the kernel's jnp mirror) on the same

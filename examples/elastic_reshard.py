@@ -17,18 +17,18 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.ft import reshard_params
+from repro.launch.mesh import make_test_mesh
 from repro.models.model import Model
 from repro.parallel import Layout
 
 cfg = get_config("qwen3-8b").reduced()
 
-from repro.parallel.compat import make_mesh
-mesh_a = make_mesh((1, 2, 2), ("data", "sp", "tp"))
+mesh_a = make_test_mesh(1, 2, 2)
 lay_a = Layout.from_mesh(mesh_a, dp=("data",), sp=("sp",), tp=("tp",))
 m_a = Model(cfg=cfg, lay=lay_a, mesh=mesh_a, dtype=jnp.float32)
 params = m_a.init_params(jax.random.key(0))
 
-mesh_b = make_mesh((1, 4, 2), ("data", "sp", "tp"))
+mesh_b = make_test_mesh(1, 4, 2)
 lay_b = Layout.from_mesh(mesh_b, dp=("data",), sp=("sp",), tp=("tp",))
 m_b = Model(cfg=cfg, lay=lay_b, mesh=mesh_b, dtype=jnp.float32)
 params_b = reshard_params(params, m_a, m_b)

@@ -1,10 +1,10 @@
 """Paged KV-cache management (vLLM-style, shard-invariant, per-dp-row).
 
-The physical KV pool is a pool of fixed-size blocks
-``[dp * num_blocks, block_size, kv_head_slots, head_dim]`` — ``num_blocks``
+The physical KV pool is a pool of fixed-size head-major blocks
+``[dp * num_blocks, kv_head_slots, block_size, head_dim]`` — ``num_blocks``
 blocks per data-parallel row, leading axis sharded over the dp mesh axes —
 whose *head* dimension carries the only model-parallel sharding:
-``P(dp_axes, None, model_axes, None)``.  Because the base (SP,TP) and shift
+``P(dp_axes, model_axes, None, None)``.  Because the base (SP,TP) and shift
 (TP) configurations share the same tp-major model group (paper §3.3.1) and
 identical dp axes, the byte-range→device map of every block is identical
 under both configs: switching parallelism moves zero bytes even though

@@ -2,9 +2,10 @@
 
 ``PagedKVCache`` is the control plane of the paged cache: for each engine
 slot it keeps the logical→physical block mapping and the number of mapped
-blocks.  The data plane — the ``[dp*num_blocks, block_size, kv_slots, Dh]``
-pools inside the jitted step functions — is owned by the model/engine; the
-manager only decides *which* physical block backs each logical block.
+blocks.  The data plane — the head-major
+``[dp*num_blocks, kv_slots, block_size, Dh]`` pools inside the jitted step
+functions — is owned by the model/engine; the manager only decides *which*
+physical block backs each logical block.
 
 Data parallelism pages per row: each dp row owns an independent
 ``BlockAllocator`` over its own ``num_blocks`` physical blocks (block 0 of
@@ -19,9 +20,9 @@ over the dp mesh axes), so host-side *global* physical ids — what
 invariance check consumes — are ``row * num_blocks + local``.
 
 Why the block layout is shard-invariant (the paper's §3.3.1 condition,
-extended to paging): a block's trailing ``[kv_slots, Dh]`` axes are sharded
-over the tp-major model group exactly like the contiguous cache's head axis,
-and the ``[block_size]`` axis is unsharded; the leading block axis is
+extended to paging): a block's ``kv_slots`` axis is sharded over the
+tp-major model group exactly like the contiguous cache's head axis, and the
+``[block_size, Dh]`` axes are unsharded; the leading block axis is
 sharded over the *dp* axes only, which both configs share untouched.  Base
 (SP,TP) and shift (TP) configs therefore assign identical byte ranges of
 every physical block to identical devices, and the block table itself is

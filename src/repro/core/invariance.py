@@ -53,9 +53,9 @@ def cache_specs_equal(shape, sharding_a: NamedSharding, sharding_b: NamedShardin
 def verify_invariance(cache_tree_shapes, base_specs, shift_specs, mesh) -> bool:
     """Check every leaf of the KV-cache pytree: base vs shift sharding must
     map identical index ranges to identical devices. Works unchanged for the
-    paged block pools ([num_blocks, block_size, slots, Dh]): only the head
-    slot axis is sharded, so the per-block byte→device map is what is
-    compared."""
+    head-major paged block pools ([num_blocks, slots, block_size, Dh]):
+    only the head slot axis is sharded, so the per-block byte→device map is
+    what is compared."""
     shapes = jax.tree.leaves(cache_tree_shapes)
     specs_a = jax.tree.leaves(base_specs, is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec))
     specs_b = jax.tree.leaves(shift_specs, is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec))
@@ -123,8 +123,8 @@ def shared_blocks_identical(pool_base, pool_shift,
     With prefix caching, a multi-ref block may be read by requests admitted
     under EITHER config — its bytes must therefore not depend on which
     config produced them, or an SP↔TP switch would silently change every
-    request that shares the block. Pool leaves are ``[num_blocks, bs,
-    slots, Dh]`` (or with a leading layer-repeat axis, found by rank)."""
+    request that shares the block. Pool leaves are ``[num_blocks, slots,
+    bs, Dh]`` (or with a leading layer-repeat axis, found by rank)."""
     blocks = np.asarray(list(shared_blocks), np.int32)
     if blocks.size == 0:
         return True

@@ -922,7 +922,7 @@ class ShiftEngine:
         preemption and reallocated as another copy's dst in the same step
         still sources its original bytes."""
         def cp(pool):
-            if pool.ndim == 5:      # [reps, num_blocks, bs, slots, Dh]
+            if pool.ndim == 5:      # [reps, num_blocks, slots, bs, Dh]
                 return pool.at[:, dst].set(pool[:, src])
             return pool.at[dst].set(pool[src])
         return jax.tree.map(cp, cache)

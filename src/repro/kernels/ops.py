@@ -84,10 +84,11 @@ DEFAULT_KERNEL_CONFIG = KernelConfig()
 
 def paged_ragged_attend(q, k_pool, v_pool, block_tables, q_lens, ctx_lens, *,
                         window=0, soft_cap=0.0, kcfg=None):
-    """Work-proportional paged attention, head-minor layout, dispatch-keyed.
+    """Work-proportional paged attention over a head-major pool,
+    dispatch-keyed.
 
     q: [B, C, Hq, D] — C ragged query columns (columns >= q_lens[b] are
-    padding); k_pool/v_pool: [num_blocks, bs, Hkv, D]; block_tables:
+    padding); k_pool/v_pool: [num_blocks, Hkv, bs, D]; block_tables:
     [B, nmax]; q_lens/ctx_lens: [B] -> [B, C, Hq, D].
 
     Plain traceable function (no jit of its own): the model calls it inside
@@ -95,8 +96,17 @@ def paged_ragged_attend(q, k_pool, v_pool, block_tables, q_lens, ctx_lens, *,
     planner guarantees ``Hq % Hkv == 0`` and group alignment."""
     backend = (kcfg or DEFAULT_KERNEL_CONFIG).resolve()
     B, C, Hq, D = q.shape
-    Hkv = k_pool.shape[2]
+    Hkv = k_pool.shape[1]
     g = Hq // Hkv
+    # With one query row per instance (g*C == 1) XLA:CPU fuses the mirror's
+    # contiguous [bs, D] pool slice into a loop-emitted vector dot, while
+    # interpret mode dots a separate block buffer: the two sum in different
+    # orders. One padding column (masked, discarded) keeps both on the
+    # matrix-matrix path, so the CPU backends stay bitwise equal.
+    pad = int(g * C == 1 and backend in ("reference", "interpret"))
+    if pad:
+        q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        C += pad
     qf = q.transpose(0, 2, 1, 3).reshape(B, Hkv, g, C, D)
     if backend == "gather":
         from . import ref
@@ -112,7 +122,7 @@ def paged_ragged_attend(q, k_pool, v_pool, block_tables, q_lens, ctx_lens, *,
                                             q_lens, ctx_lens, window=window,
                                             soft_cap=soft_cap,
                                             interpret=backend == "interpret")
-    return out.reshape(B, Hq, C, D).transpose(0, 2, 1, 3)
+    return out.reshape(B, Hq, C, D).transpose(0, 2, 1, 3)[:, :C - pad]
 
 
 @partial(jax.jit, static_argnames=("causal", "bq", "bk"))

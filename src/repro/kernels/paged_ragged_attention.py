@@ -29,6 +29,13 @@ query group as ``[g*C, D]`` rows of online-softmax state against the
 ``[bs, D]`` kv block — the KV is never expanded to the query head count,
 neither in HBM nor in VMEM.
 
+The pool is **head-major**, ``[num_blocks, Hkv, bs, D]``: one kv head's
+block is a contiguous ``[bs, D]`` tile, so the KV BlockSpec is
+``(None, None, bs, D)`` and its last two dims are the array's own — the
+shape Mosaic requires on TPU. (A head-minor ``[num_blocks, bs, Hkv, D]``
+pool would need a size-1 block on the second-minor Hkv axis, which the
+TPU compiler refuses.)
+
 Grid: ``(B*Hkv, nmax)``.  The output for ragged column ``c`` attends
 positions ``0 .. ctx_lens[b]-q_lens[b]+c`` (causal over the global
 positions of the ragged tail).
@@ -88,8 +95,8 @@ def _kernel(bt_ref, qlen_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(live)
     def _compute():
         q = q_ref[0, 0].reshape(-1, q_ref.shape[-1])    # [g*C, D]
-        k = k_ref[0, :, 0]                              # [bs, D]
-        v = v_ref[0, :, 0]
+        k = k_ref[...]                                  # [bs, D]
+        v = v_ref[...]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if soft_cap:
@@ -125,7 +132,7 @@ def paged_ragged_attention_kernel(q, k_pool, v_pool, block_tables, q_lens,
                                   ctx_lens, *, window=0, soft_cap=0.0,
                                   interpret=False):
     """q: [B, Hkv, g, C, D] — C ragged query columns per sequence;
-    k_pool/v_pool: [num_blocks, bs, Hkv, D];
+    k_pool/v_pool: [num_blocks, Hkv, bs, D] (head-major);
     block_tables: [B, nmax] (logical→physical, 0 = null block);
     q_lens: [B] fresh tokens this call (columns >= q_lens[b] are padding);
     ctx_lens: [B] total valid kv length incl. the fresh tokens — MAY exceed
@@ -135,7 +142,7 @@ def paged_ragged_attention_kernel(q, k_pool, v_pool, block_tables, q_lens,
     window: sliding-window size (0 = full causal); soft_cap: tanh logit cap
     (0 = off). Returns [B, Hkv, g, C, D]; padding columns are unspecified."""
     B, Hkv, g, C, D = q.shape
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     nmax = block_tables.shape[1]
     kern = functools.partial(_kernel, bs=bs, hkv=Hkv, C=C, scale=D ** -0.5,
                              window=window, soft_cap=soft_cap)
@@ -149,7 +156,7 @@ def paged_ragged_attention_kernel(q, k_pool, v_pool, block_tables, q_lens,
         live = ib < jnp.clip(pl.cdiv(ctx, bs), 1, nmax)
         if window:
             live &= ib >= _window_lo_block(ctx, ql[b], bs, window)
-        return (jnp.where(live, bt[b, ib], 0), 0, n % Hkv, 0)
+        return (jnp.where(live, bt[b, ib], 0), n % Hkv, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,                  # block_tables, q_lens, ctx_lens
@@ -158,8 +165,8 @@ def paged_ragged_attention_kernel(q, k_pool, v_pool, block_tables, q_lens,
             pl.BlockSpec((1, 1, g, C, D),
                          lambda n, ib, bt, ql, cl: (n // Hkv, n % Hkv,
                                                     0, 0, 0)),
-            pl.BlockSpec((1, bs, 1, D), kv_map),
-            pl.BlockSpec((1, bs, 1, D), kv_map),
+            pl.BlockSpec((None, None, bs, D), kv_map),
+            pl.BlockSpec((None, None, bs, D), kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, g, C, D),
                                lambda n, ib, bt, ql, cl: (n // Hkv, n % Hkv,
@@ -201,7 +208,7 @@ def paged_ragged_attention_mirror(q, k_pool, v_pool, block_tables, q_lens,
     algorithmic, not wall-clock; the real DMA/compute skip only exists on
     the Pallas side."""
     B, Hkv, g, C, D = q.shape
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     nmax = block_tables.shape[1]
     scale = D ** -0.5
     block_tables = block_tables.astype(jnp.int32)
@@ -234,10 +241,10 @@ def paged_ragged_attention_mirror(q, k_pool, v_pool, block_tables, q_lens,
         def compute(m, l, acc):                      # _compute
             qm = jax.lax.dynamic_index_in_dim(qf, n, 0, keepdims=False)
             blk = block_tables[b, ib]
-            k = jax.lax.dynamic_index_in_dim(k_pool, blk, 0,
-                                             keepdims=False)[:, h]
-            v = jax.lax.dynamic_index_in_dim(v_pool, blk, 0,
-                                             keepdims=False)[:, h]
+            k = jax.lax.dynamic_slice(k_pool, (blk, h, 0, 0),
+                                      (1, 1, bs, D)).reshape(bs, D)
+            v = jax.lax.dynamic_slice(v_pool, (blk, h, 0, 0),
+                                      (1, 1, bs, D)).reshape(bs, D)
             s = jax.lax.dot_general(qm, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32) * scale
             if soft_cap:

@@ -35,7 +35,9 @@ def decode_attention_ref(q, k, v, lens):
 
 def _paged_gather(pool, block_tables):
     """Assemble the logical contiguous view [B, nmax*bs, slots, Dh] of each
-    sequence's blocks. The block table is in logical order, so gathered kv
+    sequence's blocks from a head-minor ``[num_blocks, bs, slots, Dh]`` pool
+    (the ragged oracle transposes the head-major pool into this order).
+    The block table is in logical order, so gathered kv
     position ``p`` is global position ``p`` (null-block tail entries carry
     garbage and are masked by kv_len).
 
@@ -67,16 +69,16 @@ def paged_ragged_attention_ref(q, k_pool, v_pool, block_tables, q_lens,
                                ctx_lens, *, window=0, soft_cap=0.0):
     """Oracle for the ragged paged kernel. q: [B, Hkv, g, C, D] — C ragged
     query columns per sequence, column c of row b sits at global position
-    ``ctx_lens[b] - q_lens[b] + c``; k_pool/v_pool: [num_blocks, bs, Hkv, D];
-    block_tables: [B, nmax]; q_lens/ctx_lens: [B]; window/soft_cap as in
-    the kernel. Returns [B, Hkv, g, C, D]; columns >= q_lens[b] carry
-    padding positions and are don't-care (but match the kernel's masking
-    exactly)."""
+    ``ctx_lens[b] - q_lens[b] + c``; k_pool/v_pool: [num_blocks, Hkv, bs, D]
+    (head-major, as the kernel reads them); block_tables: [B, nmax];
+    q_lens/ctx_lens: [B]; window/soft_cap as in the kernel. Returns
+    [B, Hkv, g, C, D]; columns >= q_lens[b] carry padding positions and are
+    don't-care (but match the kernel's masking exactly)."""
     B, Hkv, g, C, D = q.shape
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     nmax = block_tables.shape[1]
-    kg = _paged_gather(k_pool, block_tables)
-    vg = _paged_gather(v_pool, block_tables)
+    kg = _paged_gather(k_pool.transpose(0, 2, 1, 3), block_tables)
+    vg = _paged_gather(v_pool.transpose(0, 2, 1, 3), block_tables)
     qb = q.transpose(0, 3, 1, 2, 4).reshape(B, C, Hkv * g, D)
     q_pos = ctx_lens[:, None] - q_lens[:, None] + jnp.arange(C)[None, :]
     out = _attend(qb, kg, vg, q_pos, jnp.arange(nmax * bs), causal=True,

@@ -11,10 +11,15 @@ ever spans the (slower) pod interconnect, which is what makes the design
 valid at 1000+ nodes."""
 from __future__ import annotations
 
-
-from repro.parallel.compat import make_mesh as _make_mesh
+import jax
+from jax.sharding import AxisType
 
 AXIS_POD, AXIS_DATA, AXIS_SP, AXIS_TP = "pod", "data", "sp", "tp"
+
+
+def _make_mesh(shape, axes):
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,14 +1,18 @@
 """Serving entry point.
 
-On real TPUs this runs one ShiftEngine per data-parallel row with the base
-(SP,TP) + shift (TP) compiled configs; on CPU it demonstrates the full stack
-end-to-end on a reduced model: ``PYTHONPATH=src python -m repro.launch.serve
---arch qwen3-8b --reduced``. With ``--replicas N`` the same stack runs as a
-cluster: N engine replicas behind the ``repro.cluster.Router`` (prefix-
-affinity routing, live KV migration under skew, one merged obs dump)."""
+By default it runs the reduced float32 model, which is what CPU demos and
+tests use: ``PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b``.
+``--no-reduced`` serves the published widths in bfloat16 (``--num-layers``
+cuts the depth, e.g. to one chip's share of a pipeline), and ``--sp/--tp``
+build a (1, sp, tp) mesh over the local devices with the base (SP,TP) and
+shift (TP) configs over the same KV pool. With ``--replicas N`` the same
+stack runs as a cluster: N engine replicas behind the
+``repro.cluster.Router`` (prefix-affinity routing, live KV migration under
+skew, one merged obs dump)."""
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import signal
 import time
@@ -26,6 +30,8 @@ from repro.core.policy import (DEFAULT_SHIFT_THRESHOLD, ThresholdPolicy,
 from repro.engine import (ShiftEngine, EngineConfig, FaultConfig,
                           PrefixConfig, Request, SpecConfig)
 from repro.ft import random_plan
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_test_mesh
 from repro.models import build_model
 from repro.models.model import Model
 from repro.obs import build_report, format_report, write_chrome_trace
@@ -33,27 +39,40 @@ from repro.parallel import Layout
 from repro.sim.costmodel import CostModel
 
 
-def _build_stack(arch: str, *, reduced=True, mesh=None, sp=2, tp=2,
-                 slots=8, s_max=256, chunk=64,
+def _build_stack(arch: str, *, reduced=True, num_layers=0, mesh=None,
+                 sp=1, tp=1, slots=8, s_max=256, chunk=64,
                  threshold=DEFAULT_SHIFT_THRESHOLD, adaptive=False,
                  paged=None, block_size=16, num_blocks=0, prefix_cache=False,
-                 dp=1, dtype=jnp.float32, deadline_s=None, max_queue=0,
+                 dp=1, dtype=None, deadline_s=None, max_queue=0,
                  shed_policy="reject-newest", auto_snapshot_every=0,
                  spec_k=0, spec_ngram=3):
     """Models + params + policy + EngineConfig, built once — replicas of a
     cluster share the stack (same weights: a migrated request decodes the
-    same stream on any replica)."""
+    same stream on any replica).
+
+    ``reduced`` picks the toy CPU preset (float32 by default); otherwise the
+    published widths run in bfloat16. ``num_layers`` > 0 cuts the depth.
+    With ``dp*sp*tp > 1`` (and no explicit mesh) a (dp, sp, tp) mesh is
+    built over the local devices; parameters and pools are created directly
+    in their shardings on it."""
     cfg = get_config(arch)
     if reduced:
         cfg = cfg.reduced()
-    if mesh is None and dp > 1:
-        from repro.launch.mesh import make_test_mesh
-        if len(jax.devices()) < dp:
+    if num_layers:
+        if num_layers > cfg.num_layers:
+            raise ValueError(f"num_layers={num_layers} exceeds {cfg.name}'s "
+                             f"{cfg.num_layers}")
+        cfg = dataclasses.replace(cfg, num_layers=num_layers)
+    if dtype is None:
+        dtype = jnp.float32 if reduced else jnp.bfloat16
+    if mesh is None and dp * sp * tp > 1:
+        if len(jax.devices()) < dp * sp * tp:
             raise ValueError(
-                f"dp={dp} needs {dp} devices, have {len(jax.devices())} "
-                "(set XLA_FLAGS=--xla_force_host_platform_device_count=N "
-                "before jax initializes for a CPU demo)")
-        mesh = make_test_mesh(data=dp, sp=1, tp=1)
+                f"dp*sp*tp={dp * sp * tp} needs that many devices, have "
+                f"{len(jax.devices())} (set XLA_FLAGS="
+                "--xla_force_host_platform_device_count=N before jax "
+                "initializes for a CPU demo)")
+        mesh = make_test_mesh(data=dp, sp=sp, tp=tp)
     if mesh is None:
         base = build_model(cfg, dtype=dtype)
         shift = base
@@ -65,7 +84,8 @@ def _build_stack(arch: str, *, reduced=True, mesh=None, sp=2, tp=2,
     p_base = params
     p_shift = (params if mesh is None
                else shift.init_params(jax.random.key(0)))  # separate models
-    policy = (AdaptivePolicy(CostModel(cfg), sp, tp) if adaptive
+    policy = (AdaptivePolicy(CostModel(cfg), base.lay.sp, base.lay.tp)
+              if adaptive
               else ThresholdPolicy(threshold))
     ecfg = EngineConfig(
         max_slots=slots, s_max=s_max, prefill_chunk=chunk,
@@ -81,9 +101,9 @@ def _build_stack(arch: str, *, reduced=True, mesh=None, sp=2, tp=2,
 
 def build_engine(arch: str, *, faults=None, **kw):
     """One ShiftEngine over an optional (data, sp, tp) mesh. With dp > 1
-    (and no explicit mesh) a dp×1×1 test mesh is built: the engine pages
-    per dp row — each row owns a private block pool and prefix index, and
-    queued requests are routed to the row with the most free blocks."""
+    the engine pages per dp row — each row owns a private block pool and
+    prefix index, and queued requests are routed to the row with the most
+    free blocks."""
     base, shift, p_base, p_shift, ecfg, policy = _build_stack(arch, **kw)
     return ShiftEngine(base, shift, p_base, p_shift, ecfg, policy=policy,
                        faults=faults)
@@ -179,7 +199,6 @@ def _reshard_demo(arch: str, *, requests=4, max_new=8):
     """Elastic resharding demo: a dp=2 engine reshards mid-decode to the
     merged pure-TP layout (dp merge -> wider TP) and back, and every
     stream still matches an uninterrupted reference run bit for bit."""
-    from repro.launch.mesh import make_test_mesh
     if len(jax.devices()) < 2:
         raise ValueError(
             "--reshard-demo needs >= 2 devices (set "
@@ -249,10 +268,20 @@ def _reshard_demo(arch: str, *, requests=4, max_new=8):
     return 0 if ok else 1
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-8b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="toy float32 preset (default); --no-reduced serves "
+                         "the published widths in bfloat16")
+    ap.add_argument("--num-layers", type=int, default=0,
+                    help="cut the depth to N layers (0 = all)")
+    ap.add_argument("--sp", type=int, default=1,
+                    help="sequence-parallel degree of the base config")
+    ap.add_argument("--tp", type=int, default=1,
+                    help="tensor-parallel degree of the base config; the "
+                         "shift config runs pure TP over sp*tp devices")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--adaptive", action="store_true")
@@ -318,7 +347,8 @@ def main():
                     help="elastic resharding demo: a dp=2 engine swaps its "
                          "Deployment to merged pure-TP mid-decode and back; "
                          "streams must match a static reference bit for bit")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.reshard_demo:
         raise SystemExit(_reshard_demo(args.arch, requests=args.requests,
@@ -333,7 +363,17 @@ def main():
               f"{len(faults)} faults over {args.fault_steps} steps")
     if args.metrics_refresh_s and not args.metrics_out:
         ap.error("--metrics-refresh-s requires --metrics-out")
-    kw = dict(adaptive=args.adaptive, block_size=args.block_size,
+    cfg = get_config(args.arch)
+    cfg = cfg.reduced() if args.reduced else cfg
+    depth = args.num_layers or cfg.num_layers
+    print(f"model: {cfg.name}, "
+          f"{'reduced float32 preset' if args.reduced else 'published widths, bfloat16'}, "
+          f"{depth} of {cfg.num_layers} layers"
+          f"{' (depth cut)' if depth < cfg.num_layers else ''}, "
+          f"mesh dp={args.dp} sp={args.sp} tp={args.tp}")
+    kw = dict(reduced=args.reduced, num_layers=args.num_layers,
+              sp=args.sp, tp=args.tp,
+              adaptive=args.adaptive, block_size=args.block_size,
               num_blocks=args.num_blocks, prefix_cache=args.prefix_cache,
               dp=args.dp, deadline_s=args.deadline_s,
               max_queue=args.max_queue, shed_policy=args.shed_policy,

@@ -112,7 +112,9 @@ def cache_specs(lay: Layout):
 def paged_cache_init(cfg, lay: Layout, num_blocks: int, block_size: int,
                      dtype):
     """Physical KV block pool for one attention layer:
-    ``[dp * num_blocks, block_size, slots, Dh]`` — ``num_blocks`` blocks
+    ``[dp * num_blocks, slots, block_size, Dh]`` (head-major, so one head's
+    block is the contiguous ``[block_size, Dh]`` tile the paged kernel
+    reads) — ``num_blocks`` blocks
     PER dp row, concatenated on the leading axis, which is sharded over
     the dp mesh axes so each data-parallel row owns a private pool slice
     (inside ``shard_map`` a dp shard indexes its local ``[num_blocks, ...]``
@@ -125,7 +127,7 @@ def paged_cache_init(cfg, lay: Layout, num_blocks: int, block_size: int,
     SP↔TP switching moves zero bytes. Each row's pool is shared across
     that row's sequences; ``block_tables`` assign physical blocks."""
     plan = get_plan(cfg, lay)
-    shape = (max(lay.dp, 1) * num_blocks, block_size, plan.kv_slots_total,
+    shape = (max(lay.dp, 1) * num_blocks, plan.kv_slots_total, block_size,
              cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
@@ -133,7 +135,7 @@ def paged_cache_init(cfg, lay: Layout, num_blocks: int, block_size: int,
 def paged_cache_specs(lay: Layout):
     dp = lay.dp_axes or None
     h = lay.head_spec_entry()
-    return {"k": P(dp, None, h, None), "v": P(dp, None, h, None)}
+    return {"k": P(dp, h, None, None), "v": P(dp, h, None, None)}
 
 
 def block_table_spec(lay: Layout) -> P:
@@ -305,7 +307,7 @@ def attn_decode(p, x, cache, lens, cfg, lay: Layout, *, window: int = 0,
 # work-proportional ragged kernel (``kernels.ops.paged_ragged_attend``) —
 # per-rank local heads are group-aligned by the planner, so the per-shard
 # call inside shard_map sees [B, C, Hq_loc, Dh] queries against the local
-# [num_blocks, bs, Hkv_loc, Dh] pool slice. The old materialized gather
+# head-major [num_blocks, Hkv_loc, bs, Dh] pool slice. The old materialized gather
 # (O(B·nmax·bs) per layer regardless of occupancy) survives only as the
 # reference oracle in ``kernels.ref`` (KernelConfig(attn_backend="gather")).
 
@@ -326,7 +328,7 @@ def paged_attn_mixed(p, x, cache, offsets, q_lens, block_tables, cfg,
     q, k = _qk_post(p, q, k, pos, cfg, True)
 
     kc, vc = cache["k"], cache["v"]
-    bs = kc.shape[1]
+    bs = kc.shape[2]
     nmax = block_tables.shape[1]
     # ragged scatter: only the first q_lens[b] columns are real tokens; the
     # rest (and any chunk overhang past the table when s_max % chunk != 0)
@@ -337,8 +339,9 @@ def paged_attn_mixed(p, x, cache, offsets, q_lens, block_tables, cfg,
     blk = jnp.take_along_axis(block_tables,
                               jnp.minimum(pos // bs, nmax - 1), axis=1)
     blk = jnp.where(valid, blk, 0)                              # [B, S]
-    kc = kc.at[blk, pos % bs].set(k)
-    vc = vc.at[blk, pos % bs].set(v)
+    # head-major pool: index (block, :, slot) takes k's [B, S, heads, Dh]
+    kc = kc.at[blk, :, pos % bs].set(k)
+    vc = vc.at[blk, :, pos % bs].set(v)
     out = K.paged_ragged_attend(q, kc, vc, block_tables, q_lens,
                                 offsets + q_lens,
                                 soft_cap=cfg.logits_soft_cap, kcfg=kcfg)
@@ -377,10 +380,10 @@ def paged_attn_decode(p, x, cache, lens, block_tables, cfg, lay: Layout,
     q, k = _qk_post(p, q, k, pos, cfg, True)
 
     kc, vc = cache["k"], cache["v"]
-    bs = kc.shape[1]
+    bs = kc.shape[2]
     blk = block_tables[jnp.arange(B), lens // bs]              # [B]
-    kc = kc.at[blk, lens % bs].set(k[:, 0])
-    vc = vc.at[blk, lens % bs].set(v[:, 0])
+    kc = kc.at[blk, :, lens % bs].set(k[:, 0])
+    vc = vc.at[blk, :, lens % bs].set(v[:, 0])
     out = K.paged_ragged_attend(q, kc, vc, block_tables,
                                 jnp.ones_like(lens), lens + 1,
                                 soft_cap=cfg.logits_soft_cap, kcfg=kcfg)

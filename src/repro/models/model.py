@@ -7,10 +7,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.parallel import Layout
-from repro.parallel.compat import shard_map
 from . import transformer as T
 
 POD_SCALE_ARCHS = {"deepseek-v3-671b", "llama4-maverick-400b-a17b",
@@ -39,8 +39,17 @@ class Model:
         return self.cfg.name in POD_SCALE_ARCHS
 
     # ------------------------------------------------------------ init
+    def _placed(self, init, specs):
+        """``init`` jitted so that every leaf is created directly in its
+        sharding on the mesh (on the default device without one): no leaf
+        of a sharded model is ever materialised whole on one device."""
+        out = None if self.mesh is None else self.shardings(specs)
+        return jax.jit(init, out_shardings=out)
+
     def init_params(self, key):
-        return T.init_params(key, self.cfg, self.lay, self.dtype, self.pod_scale)
+        cfg, lay, dtype, pod = self.cfg, self.lay, self.dtype, self.pod_scale
+        return self._placed(lambda k: T.init_params(k, cfg, lay, dtype, pod),
+                            self.param_specs())(key)
 
     def abstract_params(self):
         return jax.eval_shape(self.init_params, jax.random.key(0))
@@ -61,7 +70,7 @@ class Model:
     @property
     def supports_paged(self) -> bool:
         """True when every cached layer is plain GQA attention — the kinds
-        whose [block_size, kv_slots, Dh] block layout is shard-invariant.
+        whose [kv_slots, block_size, Dh] block layout is shard-invariant.
         MLA (latent layout), local (ring buffer), rglru/ssd (recurrent
         state) and encoder/decoder stacks keep the contiguous cache."""
         return (self.cfg.mla is None and not self.cfg.encoder_layers
@@ -71,8 +80,11 @@ class Model:
         """Block pools with ``num_blocks`` physical blocks PER dp row (the
         leading pool axis is ``dp * num_blocks``, sharded over the dp
         axes). With dp == 1 this is exactly the global pool size."""
-        return T.init_paged_cache(self.cfg, self.lay, num_blocks, block_size,
-                                  self.dtype)
+        cfg, lay, dtype = self.cfg, self.lay, self.dtype
+        return self._placed(
+            lambda: T.init_paged_cache(cfg, lay, num_blocks, block_size,
+                                       dtype),
+            self.paged_cache_specs())()
 
     def abstract_paged_cache(self, num_blocks: int, block_size: int):
         return jax.eval_shape(

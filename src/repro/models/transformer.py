@@ -46,17 +46,20 @@ def init_params(key, cfg, lay: Layout, dtype, pod_scale=False):
     p["suffix"] = {str(i): BK.block_init(next(ks), kinds[npre + reps * len(cfg.layer_pattern) + i],
                                          cfg, lay, dtype, pod_scale)
                    for i in range(nsuf)}
+    # stacked layers are drawn one at a time (lax.map, not vmap): a vmapped
+    # draw is not partitioned under sharded out_shardings, so it would hold
+    # every layer's float32 draw unsharded on each device at once
     body = {}
     for si, kind in enumerate(cfg.layer_pattern):
         kk = jax.random.split(next(ks), reps)
-        body[f"s{si}"] = jax.vmap(
-            lambda k: BK.block_init(k, kind, cfg, lay, dtype, pod_scale))(kk)
+        body[f"s{si}"] = jax.lax.map(
+            lambda k: BK.block_init(k, kind, cfg, lay, dtype, pod_scale), kk)
     p["body"] = body
 
     if cfg.encoder_layers:
         ek = jax.random.split(next(ks), cfg.encoder_layers)
-        p["encoder"] = jax.vmap(
-            lambda k: BK.block_init(k, "enc", cfg, lay, dtype, pod_scale))(ek)
+        p["encoder"] = jax.lax.map(
+            lambda k: BK.block_init(k, "enc", cfg, lay, dtype, pod_scale), ek)
         p["enc_norm"] = norm_init(cfg.norm, cfg.d_model, dtype)
 
     if cfg.mtp_depth:

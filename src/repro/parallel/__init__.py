@@ -1,7 +1,6 @@
 from .layout import (Layout, LayoutDelta, layout_delta, joint_axis_index,
                      psum_if, all_gather_if)
 from .heads import HeadPlan, plan_heads
-from .compat import shard_map
 
 __all__ = ["Layout", "LayoutDelta", "layout_delta", "joint_axis_index",
-           "psum_if", "all_gather_if", "HeadPlan", "plan_heads", "shard_map"]
+           "psum_if", "all_gather_if", "HeadPlan", "plan_heads"]

@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.compat import shard_map
+from jax import shard_map
 from repro.models import Model
 from repro.models import transformer as T
 from .optimizer import AdamWConfig, adamw_init, adamw_update, opt_state_specs

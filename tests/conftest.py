@@ -12,8 +12,8 @@ import pytest
 
 
 def make_mesh(shape=(1, 2, 2)):
-    from repro.parallel.compat import make_mesh as _mk
-    return _mk(shape, ("data", "sp", "tp"))
+    from repro.launch.mesh import make_test_mesh
+    return make_test_mesh(*shape)
 
 
 @pytest.fixture(scope="session")

@@ -36,8 +36,8 @@ def _ragged_setup(B, C, Hq, Hkv, D, bs, nmax, ctx, ql, seed=0):
     nblocks = int(sum(-(-c // bs) for c in ctx)) + 1
     ks = jax.random.split(jax.random.key(seed), 3)
     q = jax.random.normal(ks[0], (B, C, Hq, D), jnp.float32)
-    kp = jax.random.normal(ks[1], (nblocks, bs, Hkv, D), jnp.float32)
-    vp = jax.random.normal(ks[2], (nblocks, bs, Hkv, D), jnp.float32)
+    kp = jax.random.normal(ks[1], (nblocks, Hkv, bs, D), jnp.float32)
+    vp = jax.random.normal(ks[2], (nblocks, Hkv, bs, D), jnp.float32)
     rng = np.random.default_rng(seed)
     phys = rng.permutation(np.arange(1, nblocks))
     bt = np.zeros((B, nmax), np.int32)
@@ -70,12 +70,14 @@ def test_ragged_kernel_matches_oracle(B, C, Hq, Hkv, D, bs, nmax, ctx, ql):
 
 
 def test_ragged_kernel_decode_degenerates_to_padded():
-    """C == 1 must reproduce the padded decode kernel exactly."""
+    """C == 1 must reproduce the padded decode kernel exactly (that kernel
+    reads the head-minor ``[num_blocks, bs, Hkv, D]`` layout)."""
     q, kp, vp, bt, ql, ctx = _ragged_setup(4, 1, 8, 2, 64, 16, 8,
                                            [40, 8, 100, 128], [1, 1, 1, 1],
                                            seed=3)
     out = ops.paged_ragged_attention(q, kp, vp, bt, ql, ctx)
-    want = ops.paged_decode_attention(q, kp, vp, bt, ctx)
+    want = ops.paged_decode_attention(q, kp.transpose(0, 2, 1, 3),
+                                      vp.transpose(0, 2, 1, 3), bt, ctx)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
 
 

@@ -2,7 +2,7 @@
 compiled program with a known collective schedule, and comm-model sanity."""
 import jax
 import jax.numpy as jnp
-from repro.parallel.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from conftest import make_mesh, reduced_cfg

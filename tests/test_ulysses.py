@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from repro.parallel.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from conftest import make_mesh
@@ -20,7 +20,8 @@ def test_scatter_is_invariance_reshard():
     x = jnp.arange(2 * 8 * 8 * 3.0).reshape(2, 8, 8, 3)
     out = shard_map(lambda v: ulysses_scatter_heads([v], lay)[0], mesh=mesh,
                     in_specs=P(None, "sp", "tp", None),
-                    out_specs=P(None, None, ("tp", "sp"), None))(x)
+                    out_specs=P(None, None, ("tp", "sp"), None),
+                    check_vma=False)(x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(x))
 
 
@@ -37,7 +38,8 @@ def test_fused_roundtrip_multi_tensor():
 
     oa, ob = shard_map(f, mesh=mesh,
                        in_specs=(P(None, "sp", "tp", None),) * 2,
-                       out_specs=(P(None, "sp", "tp", None),) * 2)(x, y)
+                       out_specs=(P(None, "sp", "tp", None),) * 2,
+                       check_vma=False)(x, y)
     np.testing.assert_allclose(np.asarray(oa), np.asarray(x), atol=1e-6)
     np.testing.assert_allclose(np.asarray(ob), np.asarray(y), atol=1e-6)
 
@@ -62,7 +64,8 @@ def test_kv_send_replication(hkv, sp, tp):
         return ulysses_scatter_heads([send], lay)[0]
 
     out = shard_map(f, mesh=mesh, in_specs=P(None, "sp", "tp", None),
-                    out_specs=P(None, None, ("tp", "sp"), None))(kv)
+                    out_specs=P(None, None, ("tp", "sp"), None),
+                    check_vma=False)(kv)
     out, kvn = np.asarray(out), np.asarray(canon)
     slots = plan.kv_slots_total
     for u in range(slots):

@@ -49,8 +49,8 @@ def _setup(B, C, Hq, Hkv, D, bs, nmax, ctx, ql, seed=0):
     nblocks = sum(nbs) + 1
     ks = jax.random.split(jax.random.key(seed), 3)
     q = jax.random.normal(ks[0], (B, C, Hq, D), jnp.float32)
-    kp = jax.random.normal(ks[1], (nblocks, bs, Hkv, D), jnp.float32)
-    vp = jax.random.normal(ks[2], (nblocks, bs, Hkv, D), jnp.float32)
+    kp = jax.random.normal(ks[1], (nblocks, Hkv, bs, D), jnp.float32)
+    vp = jax.random.normal(ks[2], (nblocks, Hkv, bs, D), jnp.float32)
     rng = np.random.default_rng(seed)
     phys = rng.permutation(np.arange(1, nblocks))
     bt = np.zeros((B, nmax), np.int32)
@@ -146,8 +146,8 @@ def test_window_matches_dense_attend():
     out = np.asarray(ops.paged_ragged_attention(
         q, kp, vp, bt, qlj, ctxj, window=window,
         kcfg=KernelConfig("reference")))
-    kg = R._paged_gather(kp, bt)
-    vg = R._paged_gather(vp, bt)
+    kg = R._paged_gather(kp.transpose(0, 2, 1, 3), bt)     # head-minor view
+    vg = R._paged_gather(vp.transpose(0, 2, 1, 3), bt)
     qpos = ctxj[:, None] - qlj[:, None] + jnp.arange(C)[None, :]
     want = np.asarray(attend(q, kg, vg, qpos, jnp.arange(nmax * bs),
                              causal=True, window=window, kv_len=ctxj))

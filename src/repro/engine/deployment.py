@@ -26,6 +26,15 @@ from repro.models.model import Model
 from repro.parallel import Layout, LayoutDelta
 
 
+def _jit_step(fn, name: str):
+    """Jit a step program (the pool, argument 1, donated) under a stable
+    name: the profiler's ``XLA Modules`` line then reads ``jit_<name>``
+    (``jit_step_base``, ``jit_decode_shift``, ...), so base and shift,
+    mixed and serialized launches are told apart in a trace."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, donate_argnums=(1,))
+
+
 class ReshardError(RuntimeError):
     """A reshard request that cannot be satisfied. Raised BEFORE any
     engine state is mutated — the engine keeps serving on its current
@@ -109,31 +118,23 @@ class Deployment:
 
     def _compile(self):
         kc = self.kernel
+        models = {"base": self.base, "shift": self.shift}
         if self.mixed:
             # ONE unified program per config replaces the 2×2
             # prefill/decode table: prefill chunks and decode rows share a
             # forward pass, so the policy prices the real iteration.
             self.forward = {
-                "base": jax.jit(self.base.forward_fn(paged=True, kernel=kc),
-                                donate_argnums=(1,)),
-                "shift": jax.jit(self.shift.forward_fn(paged=True,
-                                                       kernel=kc),
-                                 donate_argnums=(1,))}
+                c: _jit_step(m.forward_fn(paged=True, kernel=kc),
+                             f"step_{c}") for c, m in models.items()}
             self.spec_forward = {}
         else:
             pg = self.paged
             self.prefill = {
-                "base": jax.jit(self.base.prefill_fn(paged=pg, kernel=kc),
-                                donate_argnums=(1,)),
-                "shift": jax.jit(self.shift.prefill_fn(paged=pg, kernel=kc),
-                                 donate_argnums=(1,))}
+                c: _jit_step(m.prefill_fn(paged=pg, kernel=kc),
+                             f"prefill_{c}") for c, m in models.items()}
             self.decode = {
-                "base": jax.jit(self.base.decode_fn(True, paged=pg,
-                                                    kernel=kc),
-                                donate_argnums=(1,)),
-                "shift": jax.jit(self.shift.decode_fn(True, paged=pg,
-                                                      kernel=kc),
-                                 donate_argnums=(1,))}
+                c: _jit_step(m.decode_fn(True, paged=pg, kernel=kc),
+                             f"decode_{c}") for c, m in models.items()}
 
     # -------------------------------------------------------- spec verify
     def forward_at(self, config: str, n_last: int = 1):
@@ -150,9 +151,9 @@ class Deployment:
         fn = self.spec_forward.get(key)
         if fn is None:
             model = self.base if config == "base" else self.shift
-            fn = jax.jit(model.forward_fn(paged=True, kernel=self.kernel,
-                                          n_last=n_last),
-                         donate_argnums=(1,))
+            fn = _jit_step(model.forward_fn(paged=True, kernel=self.kernel,
+                                            n_last=n_last),
+                           f"step_{config}_n{n_last}")
             self.spec_forward[key] = fn
         return fn
 

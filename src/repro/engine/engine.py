@@ -80,7 +80,7 @@ from repro.core.policy import DEFAULT_SHIFT_THRESHOLD, ThresholdPolicy
 from repro.ft.faults import FaultPlan, SnapshotError, corrupt_snapshot
 from repro.ft.watchdog import StragglerWatchdog
 from repro.models.model import Model
-from repro.obs import Observability, NullObs
+from repro.obs import Observability, NullObs, compile_count
 from repro.parallel import Layout, layout_delta
 from repro.spec import SpecConfig, SuffixDrafter
 from .api import (BlockLedger, EngineStats, FaultConfig, ObsConfig,
@@ -360,6 +360,8 @@ class ShiftEngine:
         # _log_step/_choose and folded into one record in step()
         self._step_stats: Optional[dict] = None
         self._step_audit: Optional[dict] = None
+        # the launched shape and KV coverage, set when a step launches
+        self._step_launch: Optional[dict] = None
 
     # ------------------------------------------- deployment (read-through)
     # Everything layout-dependent lives on self.deploy so reshard() can
@@ -1072,126 +1074,130 @@ class ShiftEngine:
         whose chunk reaches its last known token samples its next token in
         the same pass (fused prefill→first-token, one fewer iteration per
         request)."""
-        C = self.cfg.prefill_chunk
-        ready = [r for r in self.active if self._prefill_done(r)
-                 and not r.done and self._retryable(r)]
-        n_ready = len(ready)
-        rows = []                          # (req, off, q_len, produces)
-        drafts: Dict[int, List[int]] = {}  # rid -> speculative draft tokens
-        decode_rows = set()                # Requests batched as decode rows
-        protect = set()
-        for r in ready:
-            if r.slot is None:
-                continue                   # preempted by an earlier reserve
-            # coverage for the token written this step (position r.pos)
-            if self._reserve(r, r.total_tokens, protect=protect,
-                             write_from=r.pos):
-                d: List[int] = []
-                if self._spec_on:
-                    # draft at most the tokens this request can still emit
-                    # beyond the one it samples anyway, so accepted drafts
-                    # never overrun max_new_tokens or s_max
-                    d = self.drafter.propose(
-                        r.rid, r.all_tokens(),
-                        r.max_new_tokens - len(r.generated) - 1)
-                    # the draft extension must never preempt anyone or
-                    # evict cached prefixes — speculation is opportunistic;
-                    # shrink the draft until the row's free list covers it
-                    # (stage-1 COW already privatized the block holding
-                    # r.pos; extension blocks are freshly allocated)
-                    while d and not self.kv.ensure(r.slot,
-                                                   r.total_tokens + len(d)):
-                        d.pop()
-                if d:
-                    drafts[r.rid] = d
-                rows.append((r, r.pos, 1 + len(d), True))
-                decode_rows.add(r)
+        with self.obs.span("engine.plan"):
+            C = self.cfg.prefill_chunk
+            ready = [r for r in self.active if self._prefill_done(r)
+                     and not r.done and self._retryable(r)]
+            n_ready = len(ready)
+            rows = []                          # (req, off, q_len, produces)
+            drafts: Dict[int, List[int]] = {}  # rid -> speculative drafts
+            decode_rows = set()             # Requests batched as decode rows
+            protect = set()
+            for r in ready:
+                if r.slot is None:
+                    continue               # preempted by an earlier reserve
+                # coverage for the token written this step (position r.pos)
+                if self._reserve(r, r.total_tokens, protect=protect,
+                                 write_from=r.pos):
+                    d: List[int] = []
+                    if self._spec_on:
+                        # draft at most the tokens this request can still
+                        # emit beyond the one it samples anyway, so accepted
+                        # drafts never overrun max_new_tokens or s_max
+                        d = self.drafter.propose(
+                            r.rid, r.all_tokens(),
+                            r.max_new_tokens - len(r.generated) - 1)
+                        # the draft extension must never preempt anyone or
+                        # evict cached prefixes — speculation is
+                        # opportunistic; shrink the draft until the row's
+                        # free list covers it (stage-1 COW already
+                        # privatized the block holding r.pos; extension
+                        # blocks are freshly allocated)
+                        while d and not self.kv.ensure(
+                                r.slot, r.total_tokens + len(d)):
+                            d.pop()
+                    if d:
+                        drafts[r.rid] = d
+                    rows.append((r, r.pos, 1 + len(d), True))
+                    decode_rows.add(r)
+                    protect.add(r)
+            n_decode = len(decode_rows)
+            n_spec = sum(len(d) for d in drafts.values())
+            n_prefill_tok = 0
+            for r in list(self.active):
+                if r.slot is None or r.done or self._prefill_done(r) \
+                        or not self._retryable(r):
+                    continue
+                off = r.prefilled
+                end = min(off + C, r.total_tokens)
+                if end <= off:
+                    continue
+                if not self._reserve(r, end, protect=protect, write_from=off):
+                    continue
+                # the chunk runs through the LAST known token: when it reaches
+                # the end, this pass also samples the row's next token
+                rows.append((r, off, end - off, end == r.total_tokens))
                 protect.add(r)
-        n_decode = len(decode_rows)
-        n_spec = sum(len(d) for d in drafts.values())
-        n_prefill_tok = 0
-        for r in list(self.active):
-            if r.slot is None or r.done or self._prefill_done(r) \
-                    or not self._retryable(r):
-                continue
-            off = r.prefilled
-            end = min(off + C, r.total_tokens)
-            if end <= off:
-                continue
-            if not self._reserve(r, end, protect=protect, write_from=off):
-                continue
-            # the chunk runs through the LAST known token: when it reaches
-            # the end, this pass also samples the row's next token
-            rows.append((r, off, end - off, end == r.total_tokens))
-            protect.add(r)
-            n_prefill_tok += end - off
-            self.obs.emit("prefill_chunk", step=self.step_count, rid=r.rid,
-                          off=off, tokens=end - off)
-        if not rows:
-            self._log_step(0, 0, n_ready)
-            return False
+                n_prefill_tok += end - off
+                self.obs.emit("prefill_chunk", step=self.step_count, rid=r.rid,
+                              off=off, tokens=end - off)
+            if not rows:
+                self._log_step(0, 0, n_ready)
+                return False
 
-        attn_ctx = sum(off + ql for _, off, ql, _ in rows)
-        mode = self._choose(n_prefill_tok + n_decode + n_spec, n_prefill_tok,
-                            attn_ctx, len(rows),
-                            max(off + ql for _, off, ql, _ in rows),
-                            spec_tokens=n_spec)
-        model = self.base if mode == "base" else self.shift
-        params = self.p_base if mode == "base" else self.p_shift
-        # compact to active rows; bucket every axis so each (config, shape)
-        # compiles once. The chunk axis must stay divisible by the chosen
-        # config's sp degree (decode-only batches on the shift config are
-        # [R, 1] — no padded rectangle). Under dp>1 the device batch axis
-        # is sharded over dp, so each dp row's requests must land in that
-        # row's contiguous segment; every row gets the same pow2-bucketed
-        # segment width so the sharded shape stays rectangular (a row with
-        # no work this step contributes an all-padding segment whose
-        # scatters land in its null block).
-        if self.dp > 1:
-            per = [[] for _ in range(self.dp)]
-            for e in rows:
-                per[self.kv.row_of(e[0].slot)].append(e)
-            seg = _pow2(max(len(p) for p in per))
-            placed = [(ri * seg + j, e) for ri, p in enumerate(per)
-                      for j, e in enumerate(p)]
-            Rb = self.dp * seg
-        else:
-            placed = list(enumerate(rows))
-            Rb = _pow2(len(rows))
-        Cb = max(_pow2(max(ql for _, _, ql, _ in rows)),
-                 max(model.lay.sp, 1))
-        self._refresh_block_tables()
-        nmax = self.kv.max_blocks_per_seq
-        # slice the table batch to the occupied prefix: gather/scatter and
-        # attention work scale with actual cache occupancy, not s_max
-        nb = max(int(max(self.kv.n_mapped[r.slot] for r, _, _, _ in rows)), 1)
-        nbb = min(_pow2(nb), nmax)
-        toks = np.zeros((Rb, Cb), np.int32)
-        qlen = np.zeros((Rb,), np.int32)
-        offs = np.zeros((Rb,), np.int32)
-        bt = np.zeros((Rb, nbb), np.int32)
-        for i, (r, off, ql, _) in placed:
-            if ql == 1 and off == r.pos:       # decode row: O(1) last token
-                toks[i, 0] = (r.generated[-1] if r.generated
-                              else r.prompt[-1])
-            elif off == r.pos:                 # spec row: last token + draft
-                toks[i, 0] = (r.generated[-1] if r.generated
-                              else r.prompt[-1])
-                toks[i, 1:ql] = drafts[r.rid]
+            attn_ctx = sum(off + ql for _, off, ql, _ in rows)
+            mode = self._choose(n_prefill_tok + n_decode + n_spec,
+                                n_prefill_tok, attn_ctx, len(rows),
+                                max(off + ql for _, off, ql, _ in rows),
+                                spec_tokens=n_spec)
+            model = self.base if mode == "base" else self.shift
+            params = self.p_base if mode == "base" else self.p_shift
+            # compact to active rows; bucket every axis so each (config, shape)
+            # compiles once. The chunk axis must stay divisible by the chosen
+            # config's sp degree (decode-only batches on the shift config are
+            # [R, 1] — no padded rectangle). Under dp>1 the device batch axis
+            # is sharded over dp, so each dp row's requests must land in that
+            # row's contiguous segment; every row gets the same pow2-bucketed
+            # segment width so the sharded shape stays rectangular (a row with
+            # no work this step contributes an all-padding segment whose
+            # scatters land in its null block).
+            if self.dp > 1:
+                per = [[] for _ in range(self.dp)]
+                for e in rows:
+                    per[self.kv.row_of(e[0].slot)].append(e)
+                seg = _pow2(max(len(p) for p in per))
+                placed = [(ri * seg + j, e) for ri, p in enumerate(per)
+                          for j, e in enumerate(p)]
+                Rb = self.dp * seg
             else:
-                toks[i, :ql] = r.all_tokens()[off:off + ql]
-            qlen[i] = ql
-            offs[i] = off
-            bt[i] = self._bt_host[r.slot, :nbb]
-        self._apply_copies()               # COW copies land before the write
-        args = [jnp.asarray(toks), jnp.asarray(qlen), jnp.asarray(offs),
-                jnp.asarray(bt)]
-        # speculative verify width: the extraction returns each row's last
-        # n_last sampled tokens. No drafts -> n_last == 1 -> the exact
-        # (bitwise) non-speculative compiled program.
-        n_last = (_pow2(1 + max(len(d) for d in drafts.values()))
-                  if drafts else 1)
-        fwd = self.deploy.forward_at(mode, n_last)
+                placed = list(enumerate(rows))
+                Rb = _pow2(len(rows))
+            Cb = max(_pow2(max(ql for _, _, ql, _ in rows)),
+                     max(model.lay.sp, 1))
+        with self.obs.span("engine.inputs"):
+            self._refresh_block_tables()
+            nmax = self.kv.max_blocks_per_seq
+            # slice the table batch to the occupied prefix: gather/scatter and
+            # attention work scale with actual cache occupancy, not s_max
+            nb = max(int(max(self.kv.n_mapped[r.slot]
+                             for r, _, _, _ in rows)), 1)
+            nbb = min(_pow2(nb), nmax)
+            toks = np.zeros((Rb, Cb), np.int32)
+            qlen = np.zeros((Rb,), np.int32)
+            offs = np.zeros((Rb,), np.int32)
+            bt = np.zeros((Rb, nbb), np.int32)
+            for i, (r, off, ql, _) in placed:
+                if ql == 1 and off == r.pos:   # decode row: O(1) last token
+                    toks[i, 0] = (r.generated[-1] if r.generated
+                                  else r.prompt[-1])
+                elif off == r.pos:             # spec row: last token + draft
+                    toks[i, 0] = (r.generated[-1] if r.generated
+                                  else r.prompt[-1])
+                    toks[i, 1:ql] = drafts[r.rid]
+                else:
+                    toks[i, :ql] = r.all_tokens()[off:off + ql]
+                qlen[i] = ql
+                offs[i] = off
+                bt[i] = self._bt_host[r.slot, :nbb]
+            self._apply_copies()           # COW copies land before the write
+            args = [jnp.asarray(toks), jnp.asarray(qlen), jnp.asarray(offs),
+                    jnp.asarray(bt)]
+            # speculative verify width: the extraction returns each row's last
+            # n_last sampled tokens. No drafts -> n_last == 1 -> the exact
+            # (bitwise) non-speculative compiled program.
+            n_last = (_pow2(1 + max(len(d) for d in drafts.values()))
+                      if drafts else 1)
+            fwd = self.deploy.forward_at(mode, n_last)
         fault = (self.faults.at(self.step_count, "forward")
                  if self.faults is not None else None)
         if fault is not None:
@@ -1199,9 +1205,16 @@ class ShiftEngine:
         if fault is None or fault.kind == "nan":
             # "nan" models poisoned logits: the launch runs (and rewrites
             # the same KV bytes a retry will), but its outputs are garbage
-            nxt, self.cache = fwd(params, self.cache, *args,
-                                  *self._extras(Rb))
-            nxt = np.asarray(nxt)
+            with self.obs.span("engine.launch"):
+                nxt, self.cache = fwd(params, self.cache, *args,
+                                      *self._extras(Rb))
+            self._step_launch = {
+                "bucket": [Rb, Cb, nbb],
+                "kv_blocks": sum(
+                    blocks_for_tokens(off + ql, self.cfg.block_size)
+                    for _, (_, off, ql, _) in placed)}
+            with self.obs.span("engine.wait"):
+                nxt = np.asarray(nxt)
         if fault is not None:
             # failed step: no token is applied, no progress is recorded —
             # every batched request retries with backoff or quarantines.
@@ -1216,210 +1229,240 @@ class ShiftEngine:
             self._fail_step([e[0] for _, e in placed], n_ready,
                             attn_ctx if fault.kind == "nan" else 0)
             return True
-        t = self.now()
-        n_dec_emit = 0          # decode-side tokens actually delivered
-        n_accepted = 0          # accepted draft tokens across spec rows
-        rollback_blocks = 0
-        for i, (r, off, ql, produces) in placed:
-            r.last_used = self.step_count
-            d = drafts.get(r.rid) if r in decode_rows else None
-            if d is None:
-                r.prefilled = off + ql
-                self.lens[r.slot] = r.prefilled
-                self._commit_prefix(r)     # before a finish frees the slot
-                if produces:
-                    tok = int(nxt[i, n_last - 1]) if n_last > 1 \
-                        else int(nxt[i])
+        with self.obs.span("engine.apply"):
+            t = self.now()
+            n_dec_emit = 0          # decode-side tokens actually delivered
+            n_accepted = 0          # accepted draft tokens across spec rows
+            rollback_blocks = 0
+            for i, (r, off, ql, produces) in placed:
+                r.last_used = self.step_count
+                d = drafts.get(r.rid) if r in decode_rows else None
+                if d is None:
+                    r.prefilled = off + ql
+                    self.lens[r.slot] = r.prefilled
+                    self._commit_prefix(r)     # before a finish frees the slot
+                    if produces:
+                        tok = int(nxt[i, n_last - 1]) if n_last > 1 \
+                            else int(nxt[i])
+                        self._finish_token(r, tok, t)
+                        if r in decode_rows:
+                            n_dec_emit += 1
+                    continue
+                # speculative verify: row outputs o_0..o_m sit in the last
+                # m+1 extraction columns; accept the longest prefix where
+                # draft j matched output j-1, then emit o_0..o_accepted —
+                # exactly the tokens sequential greedy decode would produce
+                m = len(d)
+                out = [int(nxt[i, n_last - 1 - m + j]) for j in range(m + 1)]
+                n_acc = 0
+                while n_acc < m and d[n_acc] == out[n_acc]:
+                    n_acc += 1
+                emitted = out[:n_acc + 1]
+                # roll back rejected-draft KV first, while the slot is alive:
+                # a logical truncate of the uncommitted tail blocks (kept
+                # blocks' junk positions are masked by the context length and
+                # overwritten position-idempotently by later steps)
+                rollback_blocks += self.kv.truncate(r.slot, off + len(emitted))
+                delivered = 0
+                for j, tok in enumerate(emitted):
+                    # commit BEFORE each append with the coverage a sequential
+                    # step would have had (prefilled never exceeds the tokens
+                    # known at commit time, so the index hashes no draft junk)
+                    r.prefilled = off + j + 1
+                    self.lens[r.slot] = r.prefilled
+                    self._commit_prefix(r)
                     self._finish_token(r, tok, t)
-                    if r in decode_rows:
-                        n_dec_emit += 1
-                continue
-            # speculative verify: row outputs o_0..o_m sit in the last
-            # m+1 extraction columns; accept the longest prefix where
-            # draft j matched output j-1, then emit o_0..o_accepted —
-            # exactly the tokens sequential greedy decode would produce
-            m = len(d)
-            out = [int(nxt[i, n_last - 1 - m + j]) for j in range(m + 1)]
-            n_acc = 0
-            while n_acc < m and d[n_acc] == out[n_acc]:
-                n_acc += 1
-            emitted = out[:n_acc + 1]
-            # roll back rejected-draft KV first, while the slot is alive:
-            # a logical truncate of the uncommitted tail blocks (kept
-            # blocks' junk positions are masked by the context length and
-            # overwritten position-idempotently by later steps)
-            rollback_blocks += self.kv.truncate(r.slot, off + len(emitted))
-            delivered = 0
-            for j, tok in enumerate(emitted):
-                # commit BEFORE each append with the coverage a sequential
-                # step would have had (prefilled never exceeds the tokens
-                # known at commit time, so the index hashes no draft junk)
-                r.prefilled = off + j + 1
-                self.lens[r.slot] = r.prefilled
-                self._commit_prefix(r)
-                self._finish_token(r, tok, t)
-                delivered = j + 1
-                if r.finish_reason is not None:
-                    break                  # eos mid-accept: rest discarded
-            n_dec_emit += delivered
-            n_accepted += delivered - 1
-            self.obs.observe("spec_accepted_per_row", delivered - 1)
-        self._log_step(n_prefill_tok, n_dec_emit, n_ready, attn_ctx)
-        if n_spec:
-            self._step_stats["spec_proposed"] = n_spec
-            self._step_stats["spec_accepted"] = n_accepted
-            self.obs.inc("spec_proposed_total", n_spec)
-            if n_accepted:
-                self.obs.inc("spec_accepted_total", n_accepted)
-            if rollback_blocks:
-                self.obs.inc("spec_rollback_blocks_total", rollback_blocks)
-        return True
+                    delivered = j + 1
+                    if r.finish_reason is not None:
+                        break                  # eos mid-accept: rest discarded
+                n_dec_emit += delivered
+                n_accepted += delivered - 1
+                self.obs.observe("spec_accepted_per_row", delivered - 1)
+            self._log_step(n_prefill_tok, n_dec_emit, n_ready, attn_ctx)
+            if n_spec:
+                self._step_stats["spec_proposed"] = n_spec
+                self._step_stats["spec_accepted"] = n_accepted
+                self.obs.inc("spec_proposed_total", n_spec)
+                if n_accepted:
+                    self.obs.inc("spec_accepted_total", n_accepted)
+                if rollback_blocks:
+                    self.obs.inc("spec_rollback_blocks_total", rollback_blocks)
+            return True
 
     # --------------------------------------------------- serialized stepping
     def _run_prefill(self):
         """One chunked-prefill iteration over slots that still need their
         (re)prompt — after a preemption, prompt+generated re-prefill here."""
-        C = self.cfg.prefill_chunk
-        todo = [r for r in self.active
-                if not self._prefill_done(r) and self._retryable(r)]
-        if not todo:
-            return False
-        toks = np.zeros((self.cfg.max_slots, C), np.int32)
-        # dummy rows: dense cache -> scratch tail (their writes must not
-        # land on live offsets); paged -> offset 0 (their scatter routes to
-        # the null block regardless, and a zero context keeps the
-        # work-proportional kernel from looping s_max/bs null blocks)
-        offs = np.full((self.cfg.max_slots,),
-                       0 if self.paged else max(self.cfg.s_max - C, 0),
-                       np.int32)
-        rows = []
-        # MLA latent caches assume a uniform offset across the chunk batch
-        uniform = self.mcfg.mla is not None
-        base_off = None
-        for r in todo:
-            if r.slot is None:
-                continue                   # preempted by an earlier reserve
-            off = r.prefilled
-            if uniform and base_off is not None and off != base_off:
-                continue
-            # the final known token is fed through the decode path instead
-            seq = r.all_tokens()
-            chunk = seq[off:min(off + C, len(seq) - 1)]
-            if not chunk:
-                continue
-            if self.paged and not self._reserve(
-                    r, off + len(chunk), protect={rr for rr, _ in rows},
-                    write_from=off):
-                continue
-            toks[r.slot, :len(chunk)] = chunk
-            offs[r.slot] = off
-            rows.append((r, len(chunk)))
-            base_off = off
-            self.obs.emit("prefill_chunk", step=self.step_count, rid=r.rid,
-                          off=off, tokens=len(chunk))
-        if not rows:
-            return False
-        n_tok = sum(n for _, n in rows)
-        # what the attention path actually reads this launch: the paged
-        # kernel attends ctx = offset + C for EVERY batch row (the chunk
-        # buffer is q_lens == C wide, padding columns included, and the
-        # max_slots - len(rows) dummy rows attend a C-long null context) —
-        # logging only the real tokens would understate the occupancy
-        # witness and the policy's pricing. The dense fallback makes no
-        # work-proportionality claim; its log keeps the real-token sum.
-        if self.paged:
-            attn_ctx = sum(r.prefilled + C for r, _ in rows) \
-                + (self.cfg.max_slots - len(rows)) * C
-            ctx_max = max(r.prefilled + C for r, _ in rows)
-        else:
-            attn_ctx = sum(r.prefilled + n for r, n in rows)
-            ctx_max = max(r.prefilled + n for r, n in rows)
-        mode = self._choose(n_tok, n_tok, attn_ctx, len(rows), ctx_max)
-        params = self.p_base if mode == "base" else self.p_shift
-        extras = self._extras(self.cfg.max_slots)
-        args = [jnp.asarray(toks), jnp.asarray(offs)]
-        if self.paged:
-            args.append(jnp.asarray(self._block_tables([r for r, _ in rows])))
-            self._apply_copies()
+        with self.obs.span("engine.plan"):
+            C = self.cfg.prefill_chunk
+            todo = [r for r in self.active
+                    if not self._prefill_done(r) and self._retryable(r)]
+            if not todo:
+                return False
+            toks = np.zeros((self.cfg.max_slots, C), np.int32)
+            # dummy rows: dense cache -> scratch tail (their writes must not
+            # land on live offsets); paged -> offset 0 (their scatter routes to
+            # the null block regardless, and a zero context keeps the
+            # work-proportional kernel from looping s_max/bs null blocks)
+            offs = np.full((self.cfg.max_slots,),
+                           0 if self.paged else max(self.cfg.s_max - C, 0),
+                           np.int32)
+            rows = []
+            # MLA latent caches assume a uniform offset across the chunk batch
+            uniform = self.mcfg.mla is not None
+            base_off = None
+            for r in todo:
+                if r.slot is None:
+                    continue               # preempted by an earlier reserve
+                off = r.prefilled
+                if uniform and base_off is not None and off != base_off:
+                    continue
+                # the final known token is fed through the decode path instead
+                seq = r.all_tokens()
+                chunk = seq[off:min(off + C, len(seq) - 1)]
+                if not chunk:
+                    continue
+                if self.paged and not self._reserve(
+                        r, off + len(chunk), protect={rr for rr, _ in rows},
+                        write_from=off):
+                    continue
+                toks[r.slot, :len(chunk)] = chunk
+                offs[r.slot] = off
+                rows.append((r, len(chunk)))
+                base_off = off
+                self.obs.emit("prefill_chunk", step=self.step_count, rid=r.rid,
+                              off=off, tokens=len(chunk))
+            if not rows:
+                return False
+            n_tok = sum(n for _, n in rows)
+            # what the attention path actually reads this launch: the paged
+            # kernel attends ctx = offset + C for EVERY batch row (the chunk
+            # buffer is q_lens == C wide, padding columns included, and the
+            # max_slots - len(rows) dummy rows attend a C-long null
+            # context) — logging only the real tokens would understate the
+            # occupancy witness and the policy's pricing. The dense
+            # fallback makes no work-proportionality claim; its log keeps
+            # the real-token sum.
+            if self.paged:
+                attn_ctx = sum(r.prefilled + C for r, _ in rows) \
+                    + (self.cfg.max_slots - len(rows)) * C
+                ctx_max = max(r.prefilled + C for r, _ in rows)
+            else:
+                attn_ctx = sum(r.prefilled + n for r, n in rows)
+                ctx_max = max(r.prefilled + n for r, n in rows)
+            mode = self._choose(n_tok, n_tok, attn_ctx, len(rows), ctx_max)
+        with self.obs.span("engine.inputs"):
+            params = self.p_base if mode == "base" else self.p_shift
+            extras = self._extras(self.cfg.max_slots)
+            args = [jnp.asarray(toks), jnp.asarray(offs)]
+            if self.paged:
+                args.append(jnp.asarray(
+                    self._block_tables([r for r, _ in rows])))
+                self._apply_copies()
         fault = (self.faults.at(self.step_count, "forward")
                  if self.faults is not None else None)
         if fault is not None:
             self._fault_fired(fault)
         if fault is None or fault.kind == "nan":
-            _, self.cache = self._prefill[mode](params, self.cache, *args,
-                                                *extras)
+            with self.obs.span("engine.launch"):
+                _, self.cache = self._prefill[mode](params, self.cache,
+                                                    *args, *extras)
+            self._step_launch = self._serial_launch(
+                args, [r.prefilled + n for r, n in rows])
         if fault is not None:
             self._fail_step([r for r, _ in rows],
                             sum(1 for r in self.active
                                 if self._prefill_done(r) and not r.done),
                             attn_ctx if fault.kind == "nan" else 0)
             return True
-        for r, n in rows:
-            r.prefilled += n
-            r.last_used = self.step_count
-            self.lens[r.slot] = r.prefilled
-            self._commit_prefix(r)
-        self._log_step(n_tok, 0,
-                       sum(1 for r in self.active
-                           if self._prefill_done(r) and not r.done),
-                       attn_ctx)
-        return True
+        with self.obs.span("engine.apply"):
+            for r, n in rows:
+                r.prefilled += n
+                r.last_used = self.step_count
+                self.lens[r.slot] = r.prefilled
+                self._commit_prefix(r)
+            self._log_step(n_tok, 0,
+                           sum(1 for r in self.active
+                               if self._prefill_done(r) and not r.done),
+                           attn_ctx)
+            return True
 
     def _prefill_done(self, r) -> bool:
         return r.prefilled >= r.pos
 
     def _run_decode(self):
-        ready = [r for r in self.active
-                 if self._prefill_done(r) and not r.done
-                 and self._retryable(r)]
-        n_ready = len(ready)
-        if self.paged:
-            kept = []
+        with self.obs.span("engine.plan"):
+            ready = [r for r in self.active
+                     if self._prefill_done(r) and not r.done
+                     and self._retryable(r)]
+            n_ready = len(ready)
+            if self.paged:
+                kept = []
+                for r in ready:
+                    if r.slot is None:
+                        continue           # preempted by an earlier reserve
+                    # coverage for the token written this step (position r.pos)
+                    if self._reserve(r, r.total_tokens, protect=set(kept),
+                                     write_from=r.pos):
+                        kept.append(r)
+                ready = kept
+            if not ready:
+                return False
+            # inactive slots in the always-max_slots decode batch each read one
+            # null-block position (ctx = lens + 1 = 1) on the paged kernel path
+            attn_ctx = sum(r.pos + 1 for r in ready) \
+                + (self.cfg.max_slots - len(ready) if self.paged else 0)
+            mode = self._choose(len(ready), 0, attn_ctx, len(ready),
+                                max(r.pos + 1 for r in ready))
+        with self.obs.span("engine.inputs"):
+            params = self.p_base if mode == "base" else self.p_shift
+            toks = np.zeros((self.cfg.max_slots,), np.int32)
+            lens = np.zeros((self.cfg.max_slots,), np.int32)
             for r in ready:
-                if r.slot is None:
-                    continue                   # preempted by an earlier reserve
-                # coverage for the token written this step (position r.pos)
-                if self._reserve(r, r.total_tokens, protect=set(kept),
-                                 write_from=r.pos):
-                    kept.append(r)
-            ready = kept
-        if not ready:
-            return False
-        # inactive slots in the always-max_slots decode batch each read one
-        # null-block position (ctx = lens + 1 = 1) on the paged kernel path
-        attn_ctx = sum(r.pos + 1 for r in ready) \
-            + (self.cfg.max_slots - len(ready) if self.paged else 0)
-        mode = self._choose(len(ready), 0, attn_ctx, len(ready),
-                            max(r.pos + 1 for r in ready))
-        params = self.p_base if mode == "base" else self.p_shift
-        toks = np.zeros((self.cfg.max_slots,), np.int32)
-        lens = np.zeros((self.cfg.max_slots,), np.int32)
-        for r in ready:
-            toks[r.slot] = (r.generated[-1] if r.generated else r.prompt[-1])
-            lens[r.slot] = r.pos               # write position of this token
-        args = [jnp.asarray(toks), jnp.asarray(lens)]
-        if self.paged:
-            args.append(jnp.asarray(self._block_tables(ready)))
-            self._apply_copies()
+                toks[r.slot] = (r.generated[-1] if r.generated
+                                else r.prompt[-1])
+                lens[r.slot] = r.pos           # write position of this token
+            args = [jnp.asarray(toks), jnp.asarray(lens)]
+            if self.paged:
+                args.append(jnp.asarray(self._block_tables(ready)))
+                self._apply_copies()
         fault = (self.faults.at(self.step_count, "forward")
                  if self.faults is not None else None)
         if fault is not None:
             self._fault_fired(fault)
         if fault is None or fault.kind == "nan":
-            nxt, self.cache = self._decode[mode](params, self.cache, *args)
-            nxt = np.asarray(nxt)
+            with self.obs.span("engine.launch"):
+                nxt, self.cache = self._decode[mode](params, self.cache,
+                                                     *args)
+            self._step_launch = self._serial_launch(
+                args, [r.pos + 1 for r in ready])
+            with self.obs.span("engine.wait"):
+                nxt = np.asarray(nxt)
         if fault is not None:
             self._fail_step(list(ready), n_ready,
                             attn_ctx if fault.kind == "nan" else 0)
             return True
-        t = self.now()
-        for r in ready:
-            r.last_used = self.step_count
-            r.prefilled = r.pos + 1        # this step wrote position r.pos
-            self._commit_prefix(r)
-            self._finish_token(r, int(nxt[r.slot]), t)
-        self._log_step(0, len(ready), n_ready, attn_ctx)
-        return True
+        with self.obs.span("engine.apply"):
+            t = self.now()
+            for r in ready:
+                r.last_used = self.step_count
+                r.prefilled = r.pos + 1        # this step wrote position r.pos
+                self._commit_prefix(r)
+                self._finish_token(r, int(nxt[r.slot]), t)
+            self._log_step(0, len(ready), n_ready, attn_ctx)
+            return True
+
+    def _serial_launch(self, args, ctx) -> dict:
+        """Launch record of a serialized step: the full-slot batch's shape
+        (table width 0 on the dense cache) and, when paged, the KV blocks
+        covering each launched row's context ``ctx`` after the write."""
+        toks = args[0]
+        rec = {"bucket": [toks.shape[0], toks.shape[1] if toks.ndim > 1
+                          else 1, args[2].shape[1] if self.paged else 0]}
+        if self.paged:
+            rec["kv_blocks"] = sum(blocks_for_tokens(c, self.cfg.block_size)
+                                   for c in ctx)
+        return rec
 
     def _extras(self, batch: int):
         ex = []
@@ -1432,29 +1475,39 @@ class ShiftEngine:
         return ex
 
     def step(self) -> bool:
-        """One engine iteration. Returns False when idle."""
+        """One engine iteration. Returns False when idle. The iteration is
+        the ``engine.step`` span (carrying the step index); its phases are
+        the ``engine.*`` spans inside, whose host seconds land in the
+        record's ``host_s``."""
+        with self.obs.span("engine.step", step=self.step_count):
+            return self._step()
+
+    def _step(self) -> bool:
         t0 = self.now()
+        c0 = compile_count()
         self._step_stats = None
         self._step_audit = None
+        self._step_launch = None
         self._step_fail_flag = False
-        # fault-tolerance pre-pass: deadlines first (an expired request
-        # must not consume this step's batch space), then the step's
-        # scheduled faults (route faults preempt before admission refills
-        # the failed row's slots)
-        self._expire_deadlines()
-        self._arm_step_faults()
-        if self._pending_reshard is not None \
-                and self._pending_reshard["countdown"] <= 0:
-            # lead steps served with admissions paused; execute the swap
-            # now, before this step admits into the old layout
-            p, self._pending_reshard = self._pending_reshard, None
-            rep = self.reshard(p["layout"], mesh=p["mesh"],
-                               row_blocks=p["row_blocks"])
-            self.last_reshard_report = dataclasses.replace(
-                rep, admission_paused_steps=p["paused"])
-        elif self._pending_reshard is not None:
-            self._pending_reshard["countdown"] -= 1
-        self._admit()
+        with self.obs.span("engine.admit"):
+            # fault-tolerance pre-pass: deadlines first (an expired request
+            # must not consume this step's batch space), then the step's
+            # scheduled faults (route faults preempt before admission
+            # refills the failed row's slots)
+            self._expire_deadlines()
+            self._arm_step_faults()
+            if self._pending_reshard is not None \
+                    and self._pending_reshard["countdown"] <= 0:
+                # lead steps served with admissions paused; execute the
+                # swap now, before this step admits into the old layout
+                p, self._pending_reshard = self._pending_reshard, None
+                rep = self.reshard(p["layout"], mesh=p["mesh"],
+                                   row_blocks=p["row_blocks"])
+                self.last_reshard_report = dataclasses.replace(
+                    rep, admission_paused_steps=p["paused"])
+            elif self._pending_reshard is not None:
+                self._pending_reshard["countdown"] -= 1
+            self._admit()
         if self.mixed:
             # fused prefill+decode batch: no iteration-granularity
             # interference between a prompt burst and in-flight decodes
@@ -1471,6 +1524,11 @@ class ShiftEngine:
                "config": None, **(self._step_stats or _EMPTY_STEP)}
         if self._step_audit is not None:
             rec.update(self._step_audit)
+        if self._step_launch is not None:
+            rec.update(self._step_launch)
+        rec["compiles"] = compile_count() - c0
+        if self.obs.phases:
+            rec["host_s"] = dict(self.obs.phases)
         if self._step_fail_flag:
             rec["failed"] = True
             self.obs.inc("failed_steps_total")

@@ -182,6 +182,7 @@ def paged_ragged_attention_kernel(q, k_pool, v_pool, block_tables, q_lens,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g, C, D), q.dtype),
         interpret=interpret,
+        name="paged_ragged_attention",
     )(block_tables.astype(jnp.int32), q_lens.astype(jnp.int32),
       ctx_lens.astype(jnp.int32), q, k_pool, v_pool)
     return out

@@ -34,7 +34,7 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.models import build_model
 from repro.models.model import Model
-from repro.obs import build_report, format_report, write_chrome_trace
+from repro.obs import build_report, format_report
 from repro.parallel import Layout
 from repro.sim.costmodel import CostModel
 
@@ -321,9 +321,12 @@ def main(argv=None):
                     help="with --metrics-out: rewrite the .prom exposition "
                          "every S seconds WHILE serving (live scrape "
                          "surface), not just once at exit")
-    ap.add_argument("--trace-out", metavar="PATH",
-                    help="write a Chrome trace-event file (load in "
-                         "chrome://tracing or ui.perfetto.dev) to PATH")
+    ap.add_argument("--profile-dir", metavar="DIR",
+                    help="record a JAX profiler trace of the serve loop in "
+                         "DIR: the engine's step and phase spans "
+                         "(engine.*) beside the device ops on one clock, "
+                         "as an XPlane and a Perfetto trace "
+                         "(perfetto_trace.json.gz, ui.perfetto.dev)")
     ap.add_argument("--deadline-s", type=float, default=None,
                     help="per-request deadline (seconds past arrival); "
                          "expired requests finish with reason=timeout")
@@ -404,6 +407,9 @@ def main(argv=None):
 
     prom = (os.path.splitext(args.metrics_out)[0] + ".prom"
             if args.metrics_out else None)
+    if args.profile_dir:
+        jax.profiler.start_trace(args.profile_dir,
+                                 create_perfetto_trace=True)
     t0 = time.monotonic()
     try:
         n_refresh = serve_loop(client, refresh_s=args.metrics_refresh_s,
@@ -421,6 +427,9 @@ def main(argv=None):
             print(f"drained: replica {i}: used={led.used} "
                   f"pinned={led.pinned} blocks after shutdown")
     dt = time.monotonic() - t0
+    if args.profile_dir:
+        jax.profiler.stop_trace()
+        print(f"profiler trace written under {args.profile_dir}")
     for r in reqs:
         # read through the facade: after a live migration the submitted
         # Request object is stale (the request lives on at its new replica)
@@ -455,11 +464,6 @@ def main(argv=None):
             client.obs.write_prometheus(prom)
         print(f"metrics written: {args.metrics_out} (JSON), {prom} "
               "(Prometheus text)")
-    if args.trace_out:
-        write_chrome_trace(args.trace_out, dump)
-        print(f"chrome trace written: {args.trace_out} "
-              f"({len(dump['events'])} events, "
-              f"{len(dump['steps'])} steps)")
 
 
 if __name__ == "__main__":

@@ -11,10 +11,10 @@ simulator.
 * ``repro.obs.events`` — bounded structured event log: every request span
   point carries the monotone step index and a wall-clock timestamp.
 * ``repro.obs.observer`` — ``Observability``, the facade an emitter holds
-  (registry + events + rolling per-step audit records); ``NullObs`` is the
-  disabled twin for overhead A/Bs.
-* ``repro.obs.trace`` — Chrome trace-event (Perfetto-loadable) export of
-  the step timeline segmented by config and dp row.
+  (registry + events + rolling per-step audit records + ``span``, the
+  step's phases as profiler annotations with their host seconds);
+  ``NullObs`` is the disabled twin for overhead A/Bs. ``compile_count``
+  counts the executables the process built.
 * ``repro.obs.report`` — TTFT/TPOT/queue/E2E percentiles and the
   latency-vs-throughput tables matching the paper's evaluation, from a
   dump of either emitter.
@@ -23,11 +23,10 @@ from . import schema
 from .events import EventLog
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry, \
     merge_snapshots
-from .observer import Observability, NullObs
+from .observer import Observability, NullObs, compile_count
 from .report import build_report, format_report, latency_throughput_table
-from .trace import chrome_trace, write_chrome_trace
 
 __all__ = ["schema", "EventLog", "Counter", "Gauge", "Histogram",
            "MetricsRegistry", "merge_snapshots", "Observability", "NullObs",
-           "build_report", "format_report", "latency_throughput_table",
-           "chrome_trace", "write_chrome_trace"]
+           "compile_count", "build_report", "format_report",
+           "latency_throughput_table"]

@@ -13,21 +13,82 @@ list from a ``step_log`` list again), and the standard counters/histograms
 (steps_total{config}, token totals, step_seconds, ...) are derived from the
 record right there instead of being maintained in parallel at call sites.
 
+``span`` marks a phase of the served step: a ``jax.profiler``
+annotation, so the phase shows on the profiler's host plane on the same
+clock as the device ops whenever a trace records, and its host seconds in
+the step's ``host_s`` table. ``compile_count`` is the one process-wide
+count of executables built, read as a delta around a step.
+
 ``NullObs`` is the disabled twin — same API, no recording — used for the
 instrumented-vs-uninstrumented overhead A/B that CI gates
 (``obs.overhead_ratio``).
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import time
-from typing import List, Optional
+from typing import Dict, List, Optional
+
+import jax
 
 from . import schema
 from .events import EventLog
 from .metrics import MetricsRegistry
 
 DEFAULT_STEP_WINDOW = 1024
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compiles = 0
+_listening = False
+
+
+def _on_duration(event: str, duration: float, **_):
+    global _compiles
+    if event == COMPILE_EVENT:
+        _compiles += 1
+
+
+def compile_count() -> int:
+    """Executables built in this process (compiled, or read from the
+    persistent cache) since the first call. One listener serves every
+    engine of the process."""
+    global _listening
+    if not _listening:
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _listening = True
+    return _compiles
+
+
+class _Span:
+    """See ``Observability.span``."""
+
+    __slots__ = ("obs", "name", "step", "ann", "t0")
+
+    def __init__(self, obs: "Observability", name: str,
+                 step: Optional[int]):
+        self.obs, self.name, self.step = obs, name, step
+
+    def __enter__(self):
+        if self.step is None:
+            self.ann = jax.profiler.TraceAnnotation(self.name)
+        else:
+            self.obs.phases = {}
+            self.ann = jax.profiler.TraceAnnotation(self.name,
+                                                    step_num=self.step)
+        self.ann.__enter__()
+        self.t0 = self.obs.now()
+        return self
+
+    def __exit__(self, *exc):
+        if self.step is None:
+            key = self.name.rpartition(".")[2]
+            ph = self.obs.phases
+            ph[key] = ph.get(key, 0.0) + (self.obs.now() - self.t0)
+        return self.ann.__exit__(*exc)
+
+
+_NO_SPAN = contextlib.nullcontext()
 
 
 class Observability:
@@ -39,6 +100,8 @@ class Observability:
         self.registry = MetricsRegistry()
         self.events = EventLog(cap=event_cap)
         self.step_records: List[dict] = []
+        # host seconds per phase of the step in flight (see span)
+        self.phases: Dict[str, float] = {}
         self.enabled = True
         # cluster replica id (None standalone). Set once by the Router via
         # the engine facade; stamped centrally on every step record and
@@ -75,6 +138,16 @@ class Observability:
             reg.histogram("step_tokens").observe(n_pre + n_dec)
         return rec
 
+    def span(self, name: str, step: Optional[int] = None):
+        """Context manager around one phase of the served step. It opens a
+        ``jax.profiler.TraceAnnotation(name)`` and adds its seconds on the
+        obs clock to ``phases`` under the name's last dotted part
+        (``engine.admit`` -> ``admit``). With ``step`` it is the step's own
+        span instead: the annotation carries the index as its
+        ``step_num`` stat (trace events join step records by index) and
+        it starts an empty ``phases`` table."""
+        return _Span(self, name, step)
+
     # ------------------------------------------------------------ events
     def emit(self, kind: str, *, step: int, ts: Optional[float] = None,
              rid: Optional[int] = None, **attrs) -> Optional[dict]:
@@ -102,7 +175,7 @@ class Observability:
     # ----------------------------------------------------------- export
     def dump(self) -> dict:
         """The full observability state as one JSON-able dict — the input
-        format of ``repro.obs.report`` and ``repro.obs.trace``."""
+        format of ``repro.obs.report``."""
         return {"schema_version": schema.SCHEMA_VERSION,
                 "source": self.source,
                 "metrics": self.registry.snapshot(),
@@ -145,6 +218,9 @@ class NullObs(Observability):
 
     def record_step(self, rec: dict) -> dict:
         return rec
+
+    def span(self, name: str, step: Optional[int] = None):
+        return _NO_SPAN
 
     def emit(self, kind: str, *, step: int, ts: Optional[float] = None,
              rid: Optional[int] = None, **attrs) -> Optional[dict]:

@@ -3,9 +3,9 @@
 Every emitter — the live ``ShiftEngine`` and the event-driven ``ServeSim``
 — registers metrics and emits lifecycle events strictly from this module,
 so a trace or metrics snapshot from either can be fed to the same
-consumers (``repro.obs.report``, the Chrome-trace exporter, the CI bench
-gate) without per-emitter translation. The registry enforces it: creating
-a metric whose name, kind, or label keys are not declared here raises.
+consumers (``repro.obs.report``, the CI bench gate) without per-emitter
+translation. The registry enforces it: creating a metric whose name,
+kind, or label keys are not declared here raises.
 ``tests/test_obs.py`` additionally asserts that both emitters actually
 stay within the vocabulary and share the core subset.
 
@@ -188,7 +188,13 @@ STEP_OPTIONAL = ("n_tokens", "ctx_tokens", "ctx_max", "n_rows", "threshold",
                  # (also what the policy saw) and how many were accepted;
                  # decode_tokens counts DELIVERED tokens, so with drafts
                  # accepted it exceeds the step's decode-row count
-                 "spec_tokens", "spec_proposed", "spec_accepted")
+                 "spec_tokens", "spec_proposed", "spec_accepted",
+                 # host seconds per phase of the step ({admit, plan,
+                 # inputs, launch, wait, apply} -> s; Observability.span),
+                 # the launched shape [rows, chunk, table width], the KV
+                 # blocks the launched rows' contexts cover after the
+                 # step's write, and executables built during the step
+                 "host_s", "bucket", "kv_blocks", "compiles")
 
 # counters both the engine and the simulator must emit (the shared core of
 # the schema; either may additionally emit any other declared metric)

@@ -89,3 +89,25 @@ def test_compile_cache_honours_env(monkeypatch):
     assert path == os.path.join(root, ".jax_cache")
     with open(os.path.join(root, ".gitignore")) as f:
         assert ".jax_cache/" in f.read().split()   # never committed
+
+
+def test_profile_dir_writes_a_trace_of_the_engine_spans(monkeypatch, capsys,
+                                                        tmp_path):
+    """``--profile-dir`` records the serve loop with the JAX profiler: the
+    Perfetto trace holds the engine's step spans with their index."""
+    import glob
+    import gzip
+    import json
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: "")
+    serve.main(["--requests", "2", "--max-new", "3",
+                "--profile-dir", str(tmp_path)])
+    assert f"profiler trace written under {tmp_path}" in \
+        capsys.readouterr().out
+    path, = glob.glob(str(tmp_path / "**" / "perfetto_trace.json.gz"),
+                      recursive=True)
+    with gzip.open(path) as f:
+        doc = json.load(f)
+    evs = doc["traceEvents"] if isinstance(doc, dict) else doc
+    steps = [e for e in evs if e.get("name") == "engine.step"]
+    assert steps and all("step_num" in e["args"] for e in steps)
+    assert {"engine.launch", "engine.wait"} <= {e.get("name") for e in evs}

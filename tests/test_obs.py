@@ -1,12 +1,16 @@
 """Observability: schema-strict metrics registry + exporters, the
 one-source-of-truth step records (the step_times/step_log desync bugfix),
 engine/sim schema conformance, deterministic reports, snapshot/restore of
-observability state, and Chrome-trace validity."""
+observability state, and the served step's spans and counters (host
+phases, launched shape, KV coverage, compiles) in the step records and on
+the profiler's clock."""
 import itertools
 import json
+import warnings
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from conftest import reduced_cfg
@@ -14,8 +18,8 @@ from repro.core.policy import ThresholdPolicy
 from repro.engine import (ObsConfig, PrefixConfig, ShiftEngine,
                           EngineConfig, Request)
 from repro.models import build_model
-from repro.obs import (schema, MetricsRegistry, Observability,
-                       build_report, chrome_trace)
+from repro.obs import (schema, MetricsRegistry, NullObs, Observability,
+                       build_report)
 from repro.obs.events import EventLog
 from repro.obs.report import percentile
 
@@ -161,7 +165,6 @@ def test_engine_dump_is_deterministic(mp):
     r1, r2 = build_report(d1), build_report(d2)
     assert json.dumps(r1, sort_keys=True) == json.dumps(r2, sort_keys=True)
     assert r1["requests"]["finished"] == 3
-    assert json.dumps(chrome_trace(d1)) == json.dumps(chrome_trace(d2))
 
 
 def test_engine_lifecycle_events(mp):
@@ -320,26 +323,159 @@ def test_sim_legacy_counters_are_registry_views():
     assert cfgs <= {"base", "shift"}
 
 
-# ------------------------------------------------------------ chrome trace
-def test_chrome_trace_is_valid(mp):
-    eng = _run_engine(mp, n_req=2, prefix_cache=True)
-    tr = chrome_trace(eng.obs.dump())
-    evs = tr["traceEvents"]
-    assert isinstance(evs, list) and evs
-    for e in evs:
-        assert {"name", "ph", "pid", "tid"} <= set(e)
-        if e["ph"] != "M":
-            assert e["ts"] >= 0                   # normalized to t0
-    # async request spans balance per id
-    opens = {}
-    for e in evs:
-        if e["ph"] == "b":
-            opens[e["id"]] = opens.get(e["id"], 0) + 1
-        elif e["ph"] == "e":
-            opens[e["id"]] -= 1
-    assert opens and all(v == 0 for v in opens.values())
-    # step records appear as complete events with their audit args
-    steps = [e for e in evs if e["ph"] == "X"]
-    assert steps and all("args" in e and "dur" in e for e in steps)
-    # json-serializable as-is (what write_chrome_trace emits)
-    json.dumps(tr)
+# --------------------------------------------- step spans and counters
+PHASES = ("admit", "plan", "inputs", "launch", "wait", "apply")
+
+
+def _launched(eng):
+    return [r for r in eng.step_log if r["config"] is not None]
+
+
+@pytest.mark.parametrize("mixed", [None, False])
+def test_launched_steps_carry_host_phases(mp, mixed):
+    """Every launched step's record splits its host time by phase; the
+    phases nest inside the step, so they never sum past its duration."""
+    eng = _run_engine(mp, n_req=3, n_new=4, mixed=mixed)
+    recs = _launched(eng)
+    assert recs
+    for r in recs:
+        schema.check_step_record(r)
+        ph = r["host_s"]
+        assert set(ph) <= set(PHASES)
+        assert {"admit", "plan", "inputs", "launch", "apply"} <= set(ph), ph
+        assert all(v >= 0 for v in ph.values())
+        assert sum(ph.values()) <= r["dur_s"] + 1e-12
+    # a step that samples tokens waits for them; the serialized prefill
+    # reads nothing back
+    assert all("wait" in r["host_s"] for r in recs if r["decode_tokens"])
+
+
+def _launch_ctx(table, args):
+    """Each launched live row's context after the step's write, from the
+    arrays a step program was called with."""
+    if table == "forward":             # tokens, q_lens, offsets, tables
+        return (args[2] + args[1])[args[1] > 0]
+    live = (args[-1] != 0).any(axis=1)  # serialized: every slot launches
+    if table == "prefill":             # tokens, offsets, tables
+        return (args[1] + (args[0] != 0).sum(axis=1))[live]
+    return (args[1] + 1)[live]         # decode: tokens, lens, tables
+
+
+@pytest.mark.parametrize("mixed", [None, False])
+def test_bucket_and_kv_blocks_match_the_launched_arrays(mp, mixed):
+    m, params = mp
+    ecfg = EngineConfig(max_slots=4, s_max=64, prefill_chunk=8, mixed=mixed)
+    eng = ShiftEngine(m, m, params, params, ecfg, policy=ThresholdPolicy(4))
+    seen = []
+    for table in ("forward", "prefill", "decode"):
+        fns = getattr(eng.deploy, table) or {}
+        for mode, fn in fns.items():
+            def spy(p, cache, *args, _fn=fn, _table=table):
+                seen.append((_table, [np.asarray(a) for a in args]))
+                return _fn(p, cache, *args)
+            fns[mode] = spy
+    for i in range(3):                 # prompt tokens are never 0
+        eng.add_request(Request(i, list(range(1, 12 + 9 * i)),
+                                max_new_tokens=4))
+    eng.run_until_idle()
+    recs = _launched(eng)
+    assert len(recs) == len(seen) > 0
+    assert {t for t, _ in seen} == ({"forward"} if mixed is None
+                                    else {"prefill", "decode"})
+    bs = ecfg.block_size
+    for rec, (table, args) in zip(recs, seen):
+        toks, bt = args[0], args[-1]
+        assert rec["bucket"] == [toks.shape[0],
+                                 toks.shape[1] if toks.ndim > 1 else 1,
+                                 bt.shape[1]]
+        ctx = _launch_ctx(table, args)
+        assert len(ctx) == rec["n_rows"]
+        assert rec["kv_blocks"] == int(sum(-(-int(c) // bs) for c in ctx))
+        assert rec["kv_blocks"] <= rec["bucket"][0] * rec["bucket"][2]
+
+
+def test_compiles_counted_on_new_buckets_only(mp):
+    """A step that launches a bucket first compiles; the same bucket again
+    builds nothing."""
+    eng = _run_engine(mp, n_req=2, n_new=6)
+    recs = _launched(eng)
+    first = {}
+    for r in recs:
+        key = (r["config"], tuple(r["bucket"]))
+        if key not in first:
+            first[key] = r
+            assert r["compiles"] > 0, r
+        else:
+            assert r["compiles"] == 0, r
+    assert len(first) < len(recs)         # some bucket ran twice
+    assert all(r["compiles"] == 0 for r in eng.step_log
+               if r["config"] is None)
+
+
+def test_nullobs_span_records_nothing(mp):
+    null = NullObs()
+    assert null.span("engine.plan") is null.span("engine.step", step=3)
+    with null.span("engine.step", step=0):
+        with null.span("engine.plan"):
+            pass
+    assert null.phases == {}
+    eng = _run_engine(mp, n_req=2, obs=False)
+    assert eng.obs.phases == {} and eng.step_log == []
+
+
+def test_span_phases_add_up_and_step_span_resets(mp):
+    t = iter(range(100))
+    obs = Observability("engine", now=lambda: float(next(t)))
+    with obs.span("engine.step", step=7):
+        with obs.span("engine.plan"):
+            pass
+        with obs.span("engine.plan"):
+            pass
+        with obs.span("engine.launch"):
+            pass
+    assert obs.phases == {"plan": 2.0, "launch": 1.0}
+    with obs.span("engine.step", step=8):
+        assert obs.phases == {}
+
+
+def test_step_programs_carry_stable_names(mp):
+    mixed = _run_engine(mp, n_req=1, n_new=2)
+    assert {c: f.__name__ for c, f in mixed.deploy.forward.items()} == \
+        {"base": "step_base", "shift": "step_shift"}
+    serial = _run_engine(mp, n_req=1, n_new=2, mixed=False)
+    assert serial.deploy.prefill["shift"].__name__ == "prefill_shift"
+    assert serial.deploy.decode["base"].__name__ == "decode_base"
+
+
+def _xplane_events(trace_dir):
+    import glob
+    import os
+    path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    with warnings.catch_warnings():      # the stats type has no __module__
+        warnings.simplefilter("ignore", DeprecationWarning)
+        return [(e.name, dict(e.stats)) for plane in pd.planes
+                for line in plane.lines for e in line.events]
+
+
+def test_profiler_trace_holds_engine_spans(mp, tmp_path):
+    """The phases land on the profiler's host plane; ``engine.step``
+    carries the step index as its ``step_num`` stat (not in its name)."""
+    m, params = mp
+    ecfg = EngineConfig(max_slots=4, s_max=64, prefill_chunk=8)
+    eng = ShiftEngine(m, m, params, params, ecfg, policy=ThresholdPolicy(4))
+    eng.add_request(Request(0, list(range(1, 20)), max_new_tokens=3))
+    eng.step()                                    # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(3):
+            eng.step()
+    finally:
+        jax.profiler.stop_trace()
+    evs = _xplane_events(str(tmp_path))
+    names = {n for n, _ in evs if n.startswith("engine.")}
+    assert {"engine." + p for p in PHASES} | {"engine.step"} <= names
+    steps = [st["step_num"] for n, st in evs if n == "engine.step"]
+    assert sorted(steps) == [1, 2, 3]
+    assert [r["step"] for r in eng.step_log[1:]] == [1, 2, 3]

@@ -4,12 +4,13 @@ Interpret mode and the jnp mirror accept block shapes that the TPU's own
 compiler (Mosaic) refuses, so the kernel is compiled here, ahead of time,
 for a described v5e chip at real model widths. Nothing runs; a pass says
 the chip's compiler accepts the program and that the compiled step holds
-the kernel as a ``tpu_custom_call``.
+the kernel as a ``tpu_custom_call`` named ``paged_ragged_attention``.
 
 The topology is described inside a fixture, never while a module is
 imported: only one process may load the TPU library, and each test worker
 imports every test file."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -65,4 +66,10 @@ def test_paged_ragged_kernel_compiles_for_v5e(one_chip, case):
         sds((nb, Hkv, bs, D), jnp.bfloat16),
         sds((B, nmax), jnp.int32), sds((B,), jnp.int32),
         sds((B,), jnp.int32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's name is the custom call's instruction name, which the
+    # profiler's XLA Ops line shows (the source path alone would match
+    # the bare word)
+    assert re.search(r"%paged_ragged_attention(\.\d+)? = \S+ custom-call\(",
+                     text)

@@ -1212,7 +1212,9 @@ class ShiftEngine:
                 "bucket": [Rb, Cb, nbb],
                 "kv_blocks": sum(
                     blocks_for_tokens(off + ql, self.cfg.block_size)
-                    for _, (_, off, ql, _) in placed)}
+                    for _, (_, off, ql, _) in placed),
+                "kv_step_blocks": model.attn_step_blocks(
+                    Cb, nbb, self.cfg.block_size)}
             with self.obs.span("engine.wait"):
                 nxt = np.asarray(nxt)
         if fault is not None:
@@ -1368,7 +1370,7 @@ class ShiftEngine:
                 _, self.cache = self._prefill[mode](params, self.cache,
                                                     *args, *extras)
             self._step_launch = self._serial_launch(
-                args, [r.prefilled + n for r, n in rows])
+                mode, args, [r.prefilled + n for r, n in rows])
         if fault is not None:
             self._fail_step([r for r, _ in rows],
                             sum(1 for r in self.active
@@ -1435,7 +1437,7 @@ class ShiftEngine:
                 nxt, self.cache = self._decode[mode](params, self.cache,
                                                      *args)
             self._step_launch = self._serial_launch(
-                args, [r.pos + 1 for r in ready])
+                mode, args, [r.pos + 1 for r in ready])
             with self.obs.span("engine.wait"):
                 nxt = np.asarray(nxt)
         if fault is not None:
@@ -1452,16 +1454,20 @@ class ShiftEngine:
             self._log_step(0, len(ready), n_ready, attn_ctx)
             return True
 
-    def _serial_launch(self, args, ctx) -> dict:
+    def _serial_launch(self, mode, args, ctx) -> dict:
         """Launch record of a serialized step: the full-slot batch's shape
         (table width 0 on the dense cache) and, when paged, the KV blocks
-        covering each launched row's context ``ctx`` after the write."""
+        covering each launched row's context ``ctx`` after the write and
+        the attention kernel's KV blocks per grid step."""
         toks = args[0]
         rec = {"bucket": [toks.shape[0], toks.shape[1] if toks.ndim > 1
                           else 1, args[2].shape[1] if self.paged else 0]}
         if self.paged:
-            rec["kv_blocks"] = sum(blocks_for_tokens(c, self.cfg.block_size)
-                                   for c in ctx)
+            bs = self.cfg.block_size
+            rec["kv_blocks"] = sum(blocks_for_tokens(c, bs) for c in ctx)
+            model = self.base if mode == "base" else self.shift
+            rec["kv_step_blocks"] = model.attn_step_blocks(
+                rec["bucket"][1], rec["bucket"][2], bs)
         return rec
 
     def _extras(self, batch: int):

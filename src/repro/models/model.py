@@ -10,6 +10,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.kernels.paged_ragged_attention import kv_step_blocks
 from repro.parallel import Layout
 from . import transformer as T
 
@@ -96,6 +97,20 @@ class Model:
     def block_table_spec(self):
         from .attention import block_table_spec
         return block_table_spec(self.lay)
+
+    def attn_step_blocks(self, chunk: int, table_width: int,
+                         block_size: int) -> int:
+        """KV blocks per grid step of the paged attention kernel in a step
+        launched with ``chunk`` query columns and block tables
+        ``table_width`` wide: ``kv_step_blocks`` at the shapes each shard's
+        kernel call sees (its own kv heads and their query groups; the
+        chunk is whole after the sequence exchange)."""
+        from .attention import get_plan
+        plan = get_plan(self.cfg, self.lay)
+        g = plan.q_per_rank // plan.kv_per_rank
+        return kv_step_blocks(bs=block_size, nmax=table_width,
+                              rows=g * chunk, head_dim=self.cfg.head_dim,
+                              kv_heads=plan.kv_per_rank, dtype=self.dtype)
 
     # ---------------------------------------------------------- step fns
     # All bodies are closed over (cfg, lay) and run inside shard_map when a

@@ -193,8 +193,10 @@ STEP_OPTIONAL = ("n_tokens", "ctx_tokens", "ctx_max", "n_rows", "threshold",
                  # inputs, launch, wait, apply} -> s; Observability.span),
                  # the launched shape [rows, chunk, table width], the KV
                  # blocks the launched rows' contexts cover after the
-                 # step's write, and executables built during the step
-                 "host_s", "bucket", "kv_blocks", "compiles")
+                 # step's write, the attention kernel's KV blocks per grid
+                 # step, and executables built during the step
+                 "host_s", "bucket", "kv_blocks", "kv_step_blocks",
+                 "compiles")
 
 # counters both the engine and the simulator must emit (the shared core of
 # the schema; either may additionally emit any other declared metric)

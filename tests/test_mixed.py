@@ -70,15 +70,18 @@ def test_ragged_kernel_matches_oracle(B, C, Hq, Hkv, D, bs, nmax, ctx, ql):
 
 
 def test_ragged_kernel_decode_degenerates_to_padded():
-    """C == 1 must reproduce the padded decode kernel exactly (that kernel
-    reads the head-minor ``[num_blocks, bs, Hkv, D]`` layout)."""
+    """C == 1 must reproduce the padded decode kernel (that kernel reads
+    the head-minor ``[num_blocks, bs, Hkv, D]`` layout) to float32
+    rounding. Not bitwise: that kernel folds one 16-token block at a time
+    into its online softmax, the ragged kernel a grid step of P blocks."""
     q, kp, vp, bt, ql, ctx = _ragged_setup(4, 1, 8, 2, 64, 16, 8,
                                            [40, 8, 100, 128], [1, 1, 1, 1],
                                            seed=3)
     out = ops.paged_ragged_attention(q, kp, vp, bt, ql, ctx)
     want = ops.paged_decode_attention(q, kp.transpose(0, 2, 1, 3),
                                       vp.transpose(0, 2, 1, 3), bt, ctx)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=2e-6, rtol=0)
 
 
 def test_ragged_kernel_empty_row_is_zero_and_isolated():

@@ -4,6 +4,7 @@ engine/sim schema conformance, deterministic reports, snapshot/restore of
 observability state, and the served step's spans and counters (host
 phases, launched shape, KV coverage, compiles) in the step records and on
 the profiler's clock."""
+import importlib
 import itertools
 import json
 import warnings
@@ -392,6 +393,32 @@ def test_bucket_and_kv_blocks_match_the_launched_arrays(mp, mixed):
         assert len(ctx) == rec["n_rows"]
         assert rec["kv_blocks"] == int(sum(-(-int(c) // bs) for c in ctx))
         assert rec["kv_blocks"] <= rec["bucket"][0] * rec["bucket"][2]
+
+
+@pytest.mark.parametrize("mixed", [None, False])
+def test_launch_record_carries_kv_step_blocks(mp, mixed, monkeypatch):
+    """Each paged launch records ``kv_step_blocks``, the KV blocks per grid
+    step of its attention kernel: the engine asks the kernel's own helper
+    at the shapes the kernel sees, so the record matches what the kernel
+    traced with at that table width."""
+    # the package re-exports a function under the module's name
+    pra = importlib.import_module("repro.kernels.paged_ragged_attention")
+    helper, traced = pra.kv_step_blocks, set()
+
+    def spy(**shapes):
+        p = helper(**shapes)
+        traced.add((shapes["nmax"], p))
+        return p
+
+    monkeypatch.setattr(pra, "kv_step_blocks", spy)
+    eng = _run_engine(mp, n_req=3, n_new=4, mixed=mixed)
+    recs = _launched(eng)
+    assert recs and traced
+    for r in recs:
+        schema.check_step_record(r)
+        p = r["kv_step_blocks"]
+        assert 1 <= p <= r["bucket"][2]
+        assert (r["bucket"][2], p) in traced
 
 
 def test_compiles_counted_on_new_buckets_only(mp):

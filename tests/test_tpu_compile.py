@@ -17,7 +17,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.paged_ragged_attention import paged_ragged_attention_kernel
+from repro.kernels.paged_ragged_attention import (
+    TOKENS_PER_STEP, VMEM_BUDGET, kv_step_blocks, kv_step_vmem_bytes,
+    paged_ragged_attention_kernel)
 
 
 @pytest.fixture(scope="module")
@@ -48,6 +50,8 @@ CASES = {
     "qwen3-8b-spec-verify": (8, 8, 4, 4, 128, 16, 256, 2049, 0, 0.0),
     "qwen2-1.5b-chunk": (8, 2, 6, 64, 128, 16, 256, 2049, 0, 0.0),
     "window-softcap": (8, 8, 4, 64, 128, 16, 256, 2049, 4096, 50.0),
+    "qwen2-1.5b-longdoc-decode": (32, 2, 6, 1, 128, 16, 608, 12921, 0, 0.0),
+    "qwen2-1.5b-prefill-256": (8, 2, 6, 256, 128, 16, 608, 12921, 0, 0.0),
 }
 
 
@@ -73,3 +77,17 @@ def test_paged_ragged_kernel_compiles_for_v5e(one_chip, case):
     # the bare word)
     assert re.search(r"%paged_ragged_attention(\.\d+)? = \S+ custom-call\(",
                      text)
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_kv_step_blocks_fit_the_vmem_budget(case):
+    """The kernel's blocks per grid step at each compiled shape: at least
+    one, no wider than the table or the aimed-for keys a step, and a step's
+    VMEM estimate within the budget."""
+    B, Hkv, g, C, D, bs, nmax, nb, window, cap = CASES[case]
+    shapes = dict(bs=bs, rows=g * C, head_dim=D, kv_heads=Hkv,
+                  dtype=jnp.bfloat16)
+    p = kv_step_blocks(nmax=nmax, **shapes)
+    assert 1 <= p <= nmax
+    assert p * bs <= TOKENS_PER_STEP
+    assert kv_step_vmem_bytes(p, **shapes) <= VMEM_BUDGET

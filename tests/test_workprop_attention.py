@@ -73,6 +73,16 @@ CASES = [
     # degenerate-prefill overhang: ctx past the table (s_max % chunk != 0
     # padding) — positions beyond nmax*bs are absent, never wrapped/clipped
     (2, 8, 4, 2, 32, 8, 4, [36, 20], [8, 8], 0, 0.0),
+    # rows over several grid steps: bs 64 gives P = 8 blocks a step.
+    # Table width 10, not a multiple of P: the last step is 2 blocks wide
+    (3, 4, 4, 2, 32, 64, 10, [600, 130, 300], [1, 4, 2], 0, 0.0),
+    # occupancy ending mid-step beside rows that fill whole steps
+    (3, 2, 4, 2, 32, 64, 16, [1024, 699, 512], [2, 1, 2], 0, 0.0),
+    # an empty row between two live rows: nothing prefetches row 2's
+    # first step, so it fetches its own blocks
+    (3, 2, 4, 2, 32, 64, 16, [700, 0, 900], [2, 0, 1], 0, 0.0),
+    # window whose first live block falls mid-step (blocks 4 and 12)
+    (3, 4, 4, 2, 32, 64, 24, [900, 1400, 300], [4, 2, 3], 600, 0.0),
 ]
 
 

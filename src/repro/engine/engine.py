@@ -98,6 +98,28 @@ _EMPTY_STEP = {"prefill_tokens": 0, "decode_tokens": 0, "ready_decodes": 0,
                "attn_ctx_tokens": 0}
 
 
+# the most padded tokens that ready decode rows may add to a mixed launch
+# carrying prefill chunks (ShiftEngine._ride): on qwen3-8b over a 2x2 v5e a
+# 16-row shift decode step (31.8 ms) cost what ~700-900 padded tokens of a
+# 16 x 256 base step (186 ms) did
+DECODE_RIDE_TOKENS = 1024
+
+
+def balanced_chunk(left: int, chunk: int) -> int:
+    """Tokens of a row's next prefill chunk, ``left`` tokens from its offset
+    through its last known token. The fewest chunks that reach the last
+    token's position share the tokens near-equally, so none is shorter than
+    about half the chunk unless the whole remainder is (a short last chunk
+    would let the threshold policy send a prefill step to the shift config).
+    Where equal shares would overrun ``chunk`` the last token rides the
+    decode path instead, as it does when fixed chunks end one token short."""
+    n = -(-(left - 1) // chunk)
+    if n <= 0:
+        return 0
+    q = -(-left // n)
+    return q if q <= chunk else -(-(left - 1) // n)
+
+
 class EngineConfig:
     """Engine configuration: scheduling/paging knobs flat, the accreted
     prefix/fault/observability flags grouped into nested dataclasses
@@ -277,6 +299,9 @@ class ShiftEngine:
         # for its lead steps so the re-pour moves fewer blocks
         self._pending_reshard: Optional[dict] = None
         self.last_reshard_report: Optional[ReshardReport] = None
+        # set by a mixed step that left ready decode rows out (_ride): the
+        # next step launches them alone
+        self._decode_turn = False
         # ONE swappable value owns everything layout-dependent: the model
         # views, the sharded params, and the jit tables. reshard() replaces
         # it wholesale; base/shift/p_base/p_shift/dp/_forward/_prefill/
@@ -998,10 +1023,8 @@ class ShiftEngine:
         don't see the context)."""
         facts = {"ctx_tokens": ctx_tokens, "n_rows": n_rows,
                  "ctx_max": ctx_max, "spec_tokens": spec_tokens}
-        use_base = self.policy.use_base(
-            n_tokens, n_prefill,
-            **{k: facts[k] for k in self._policy_ctx_kwargs})
-        name = "base" if use_base else "shift"
+        name = "base" if self._use_base(n_tokens, n_prefill, facts) \
+            else "shift"
         # shift-decision audit: the chosen config AND exactly the facts the
         # policy saw, folded into this step's record by step() — a base<->
         # shift flip is explainable from the trace alone
@@ -1013,6 +1036,19 @@ class ShiftEngine:
         if spec_tokens:
             self._step_audit["spec_tokens"] = spec_tokens
         return name
+
+    def _use_base(self, n_tokens: int, n_prefill: int, facts: dict) -> bool:
+        return self.policy.use_base(
+            n_tokens, n_prefill,
+            **{k: facts[k] for k in self._policy_ctx_kwargs})
+
+    def _rows_use_base(self, rows, n_prefill: int) -> bool:
+        """The policy's choice for a launch of ``rows`` (no drafts)."""
+        ends = [off + ql for _, off, ql, _ in rows]
+        return self._use_base(
+            sum(ql for _, _, ql, _ in rows), n_prefill,
+            {"ctx_tokens": sum(ends), "n_rows": len(rows),
+             "ctx_max": max(ends), "spec_tokens": 0})
 
     def _log_step(self, n_prefill: int, n_decode: int, n_ready: int,
                   attn_ctx: int = 0):
@@ -1079,6 +1115,7 @@ class ShiftEngine:
             ready = [r for r in self.active if self._prefill_done(r)
                      and not r.done and self._retryable(r)]
             n_ready = len(ready)
+            decode_turn, self._decode_turn = self._decode_turn, False
             rows = []                          # (req, off, q_len, produces)
             drafts: Dict[int, List[int]] = {}  # rid -> speculative drafts
             decode_rows = set()             # Requests batched as decode rows
@@ -1114,12 +1151,12 @@ class ShiftEngine:
             n_decode = len(decode_rows)
             n_spec = sum(len(d) for d in drafts.values())
             n_prefill_tok = 0
-            for r in list(self.active):
+            for r in ([] if decode_turn and rows else list(self.active)):
                 if r.slot is None or r.done or self._prefill_done(r) \
                         or not self._retryable(r):
                     continue
                 off = r.prefilled
-                end = min(off + C, r.total_tokens)
+                end = off + balanced_chunk(r.total_tokens - off, C)
                 if end <= off:
                     continue
                 if not self._reserve(r, end, protect=protect, write_from=off):
@@ -1134,6 +1171,10 @@ class ShiftEngine:
             if not rows:
                 self._log_step(0, 0, n_ready)
                 return False
+            if n_prefill_tok and decode_rows and not drafts:
+                rows, decode_rows = self._ride(rows, decode_rows,
+                                               n_prefill_tok, C)
+                n_decode = len(decode_rows)
 
             attn_ctx = sum(off + ql for _, off, ql, _ in rows)
             mode = self._choose(n_prefill_tok + n_decode + n_spec,
@@ -1150,32 +1191,31 @@ class ShiftEngine:
             # row's contiguous segment; every row gets the same pow2-bucketed
             # segment width so the sharded shape stays rectangular (a row with
             # no work this step contributes an all-padding segment whose
-            # scatters land in its null block).
-            if self.dp > 1:
-                per = [[] for _ in range(self.dp)]
-                for e in rows:
-                    per[self.kv.row_of(e[0].slot)].append(e)
-                seg = _pow2(max(len(p) for p in per))
-                placed = [(ri * seg + j, e) for ri, p in enumerate(per)
-                          for j, e in enumerate(p)]
-                Rb = self.dp * seg
-            else:
-                placed = list(enumerate(rows))
-                Rb = _pow2(len(rows))
+            # scatters land in its null block). A decode-only step launches
+            # every slot's row: it costs about one read of the weights
+            # whatever its rows, so it keeps one shape per config.
+            full = not n_prefill_tok and not drafts
+            placed, Rb = self._place(rows, full)
             Cb = max(_pow2(max(ql for _, _, ql, _ in rows)),
                      max(model.lay.sp, 1))
+            if n_prefill_tok:
+                # chunks run from about C/2 to C tokens (balanced_chunk), a
+                # short prompt's from 1: launching every step that carries
+                # a prefill chunk at the chunk width keeps one chunk bucket
+                # per config, so a warm-up can cover the shapes a window
+                # meets
+                Cb = max(Cb, _pow2(C))
         with self.obs.span("engine.inputs"):
             self._refresh_block_tables()
+            # every launch carries the whole table width: the kernel (and its
+            # mirror) skips grid steps past each row's occupancy, so the width
+            # costs only those empty steps, and with one width per config a
+            # warm-up has only the row buckets to compile
             nmax = self.kv.max_blocks_per_seq
-            # slice the table batch to the occupied prefix: gather/scatter and
-            # attention work scale with actual cache occupancy, not s_max
-            nb = max(int(max(self.kv.n_mapped[r.slot]
-                             for r, _, _, _ in rows)), 1)
-            nbb = min(_pow2(nb), nmax)
             toks = np.zeros((Rb, Cb), np.int32)
             qlen = np.zeros((Rb,), np.int32)
             offs = np.zeros((Rb,), np.int32)
-            bt = np.zeros((Rb, nbb), np.int32)
+            bt = np.zeros((Rb, nmax), np.int32)
             for i, (r, off, ql, _) in placed:
                 if ql == 1 and off == r.pos:   # decode row: O(1) last token
                     toks[i, 0] = (r.generated[-1] if r.generated
@@ -1188,7 +1228,7 @@ class ShiftEngine:
                     toks[i, :ql] = r.all_tokens()[off:off + ql]
                 qlen[i] = ql
                 offs[i] = off
-                bt[i] = self._bt_host[r.slot, :nbb]
+                bt[i] = self._bt_host[r.slot]
             self._apply_copies()           # COW copies land before the write
             args = [jnp.asarray(toks), jnp.asarray(qlen), jnp.asarray(offs),
                     jnp.asarray(bt)]
@@ -1209,12 +1249,12 @@ class ShiftEngine:
                 nxt, self.cache = fwd(params, self.cache, *args,
                                       *self._extras(Rb))
             self._step_launch = {
-                "bucket": [Rb, Cb, nbb],
+                "bucket": [Rb, Cb, nmax],
                 "kv_blocks": sum(
                     blocks_for_tokens(off + ql, self.cfg.block_size)
                     for _, (_, off, ql, _) in placed),
                 "kv_step_blocks": model.attn_step_blocks(
-                    Cb, nbb, self.cfg.block_size)}
+                    Cb, nmax, self.cfg.block_size)}
             with self.obs.span("engine.wait"):
                 nxt = np.asarray(nxt)
         if fault is not None:
@@ -1290,6 +1330,50 @@ class ShiftEngine:
                 if rollback_blocks:
                     self.obs.inc("spec_rollback_blocks_total", rollback_blocks)
             return True
+
+    def _place(self, rows, full: bool = False):
+        """Launch row of each batch row, and the launch's row count: pow2
+        bucketed, per dp row segment under dp>1; ``full`` launches every
+        slot's row."""
+        if self.dp > 1:
+            per = [[] for _ in range(self.dp)]
+            for e in rows:
+                per[self.kv.row_of(e[0].slot)].append(e)
+            seg = _pow2(self.slots_per_row if full
+                        else max(len(p) for p in per))
+            return ([(ri * seg + j, e) for ri, p in enumerate(per)
+                     for j, e in enumerate(p)], self.dp * seg)
+        return (list(enumerate(rows)),
+                _pow2(self.cfg.max_slots if full else len(rows)))
+
+    def _ride(self, rows, decode_rows, n_prefill: int, chunk: int):
+        """Which ready decode rows ride in a launch that carries prefill
+        chunks. Every row of that launch is padded to the chunk width, so
+        a decode row there costs a chunk of compute, while a decode-only
+        launch costs about one read of the weights whatever its rows. The
+        decode rows all ride while the padded tokens they add stay within
+        ``DECODE_RIDE_TOKENS``, or where the policy would run them alone in
+        the same config as the launch (a second launch of one program
+        pays its fixed costs twice: on one chip the scan's pool copies
+        lead a step). Otherwise only those that fit the rows the prefill
+        rows pad anyway ride, and the next step launches the decode rows
+        alone: prefill in base, decode in shift. Returns the rows to launch
+        and the decode rows among them."""
+        pre = [e for e in rows if e[0] not in decode_rows]
+        rb_pre = self._place(pre)[1]
+        if (self._place(rows)[1] - rb_pre) * _pow2(chunk) \
+                <= DECODE_RIDE_TOKENS \
+                or self._rows_use_base(rows, n_prefill) == self._rows_use_base(
+                    [e for e in rows if e[0] in decode_rows], 0):
+            return rows, decode_rows
+        keep = list(pre)
+        for e in rows:
+            if e[0] in decode_rows and self._place(keep + [e])[1] == rb_pre:
+                keep.append(e)
+        riding = {e[0] for e in keep} & decode_rows
+        self._decode_turn = True
+        return ([e for e in rows
+                 if e[0] not in decode_rows or e[0] in riding], riding)
 
     # --------------------------------------------------- serialized stepping
     def _run_prefill(self):

@@ -17,6 +17,8 @@ import pytest
 from conftest import reduced_cfg
 from repro.core.policy import ThresholdPolicy
 from repro.engine import ShiftEngine, EngineConfig, Request
+from repro.engine import engine as engine_mod
+from repro.engine.engine import balanced_chunk
 from repro.kernels import ops
 from repro.kernels import ref as R
 from repro.models import build_model
@@ -183,6 +185,101 @@ def test_mixed_equivalence_under_memory_pressure():
     assert g_mix == g_ser
     assert e_mix.preemptions > 0                 # pressure was real
     assert e_mix.kv.num_used_blocks == 0         # no block leaks
+
+
+@pytest.mark.parametrize("left,chunk,want", [
+    (25, 8, [8, 8, 8]), (20, 8, [7, 7, 6]), (12, 8, [6, 6]), (9, 8, [8]),
+    (2, 8, [2]), (300, 256, [150, 150]), (1025, 256, [256] * 4), (1, 8, []),
+])
+def test_balanced_chunks(left, chunk, want):
+    """Prefill chunks reach the last known token's position in the fewest
+    steps and share the tokens near-equally; where equal shares would
+    overrun the chunk the 1-token tail rides the decode path."""
+    got = []
+    while (q := balanced_chunk(left, chunk)):
+        got.append(q)
+        left -= q
+    assert got == want
+    assert left in (0, 1)
+
+
+def test_mixed_launch_shapes_are_few():
+    """A decode-only step launches every slot's row, a step that carries a
+    prefill chunk launches at the chunk width, and every launch carries
+    the whole block table: each config meets one shape per row bucket."""
+    cfg = reduced_cfg("qwen3-8b")
+    m = build_model(cfg, dtype=jnp.float32)
+    params = m.init_params(jax.random.key(0))
+    prompts = [list(range(1, 12 + 7 * i)) for i in range(3)]
+    _, eng = _run_engine(m, params, True, prompts)
+    recs = [r for r in eng.step_log if r["config"]]
+    nmax = eng.kv.max_blocks_per_seq
+    assert recs and all(r["bucket"][2] == nmax for r in recs)
+    pre = {tuple(r["bucket"]) for r in recs if r["prefill_tokens"]}
+    dec = {tuple(r["bucket"]) for r in recs if not r["prefill_tokens"]}
+    assert pre and all(c == 8 for _, c, _ in pre)
+    assert dec == {(4, 1, nmax)}
+
+
+@pytest.mark.parametrize("n_pre,n_dec,chunk,riding", [
+    (1, 15, 256, 0),     # 15 rows of 256 padded tokens: all wait
+    (3, 13, 256, 1),     # the prefill rows pad to 4: one rides
+    (12, 4, 256, 4),     # the prefill rows pad to 16 anyway: all ride
+    (1, 3, 256, 3),      # 3 x 256 padded tokens, within 1024: all ride
+])
+def test_decode_rows_ride_where_their_padding_is_cheap(n_pre, n_dec, chunk,
+                                                       riding):
+    """Decode rows ride in a launch carrying prefill chunks while the
+    padded tokens they add stay within ``DECODE_RIDE_TOKENS`` (1024), or
+    in the rows the prefill rows pad anyway; the rest take the next step.
+    Under ThresholdPolicy(32) the launch runs base and 16 decode rows alone
+    would run shift; under ThresholdPolicy(4) both run base, and every
+    decode row rides."""
+    cfg = reduced_cfg("qwen3-8b")
+    m = build_model(cfg, dtype=jnp.float32)
+    params = m.init_params(jax.random.key(0))
+    ecfg = EngineConfig(max_slots=16, s_max=64, prefill_chunk=chunk,
+                        block_size=8)
+    eng = ShiftEngine(m, m, params, params, ecfg, policy=ThresholdPolicy(32))
+    reqs = [Request(i, [1, 2, 3], max_new_tokens=2)
+            for i in range(n_pre + n_dec)]
+    for i, r in enumerate(reqs):
+        r.slot = i
+    rows = ([(r, 0, chunk, False) for r in reqs[:n_pre]]
+            + [(r, 3, 1, True) for r in reqs[n_pre:]])
+    dec = set(reqs[n_pre:])
+    kept, rode = eng._ride(rows, dec, n_pre * chunk, chunk)
+    assert len(rode) == riding and rode <= dec
+    assert [e for e in kept if e[0] not in dec] == rows[:n_pre]
+    assert eng._decode_turn == (riding < n_dec)
+    eng._decode_turn = False
+    eng.policy = ThresholdPolicy(4)
+    assert eng._ride(rows, dec, n_pre * chunk, chunk) == (rows, dec)
+    assert not eng._decode_turn
+
+
+def test_decode_rows_left_out_take_the_next_step(monkeypatch):
+    """With no padded tokens allowed, a step carrying prefill chunks
+    launches decode rows only in the rows its prefill rows pad anyway; the
+    next step launches the decode rows alone, and the token streams stay
+    those of the serialized engine."""
+    cfg = reduced_cfg("qwen3-8b")
+    m = build_model(cfg, dtype=jnp.float32)
+    params = m.init_params(jax.random.key(0))
+    prompts = [list(range(1, 12 + i)) for i in range(3)] + [list(range(2, 40))]
+    burst = [list(range(3, 30)), list(range(5, 26))]
+    monkeypatch.setattr(engine_mod, "DECODE_RIDE_TOKENS", 0)
+    g_mix, e_mix = _run_engine(m, params, True, prompts, burst=list(burst))
+    g_ser, _ = _run_engine(m, params, False, prompts, burst=list(burst))
+    assert g_mix == g_ser
+    recs = [r for r in e_mix.step_log if r["config"]]
+    left = [i for i, r in enumerate(recs) if r["prefill_tokens"]
+            and r["decode_tokens"] < r["ready_decodes"]]
+    assert left, "no step left a ready decode row out"
+    for i in left:
+        nxt = recs[i + 1]
+        assert not nxt["prefill_tokens"] and nxt["decode_tokens"]
+        assert nxt["bucket"][:2] == [4, 1]
 
 
 def test_policy_sees_combined_mixed_tokens():

@@ -17,8 +17,9 @@ protocol (validate -> plan -> mutate) in ``ShiftEngine.reshard``.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
-from typing import Optional, Tuple
+import functools
+from dataclasses import dataclass, field
+from typing import Optional, Set, Tuple
 
 import jax
 
@@ -33,6 +34,17 @@ def _jit_step(fn, name: str):
     mixed and serialized launches are told apart in a trace."""
     fn.__name__ = fn.__qualname__ = name
     return jax.jit(fn, donate_argnums=(1,))
+
+
+def _recorded(fn, built: set):
+    """``fn``, adding the ``(rows, chunk)`` shape of each launch that has
+    run to ``built``: a shape that has run once is compiled."""
+    @functools.wraps(fn)
+    def call(params, cache, toks, *rest):
+        out = fn(params, cache, toks, *rest)
+        built.add(tuple(toks.shape))
+        return out
+    return call
 
 
 class ReshardError(RuntimeError):
@@ -70,7 +82,9 @@ class Deployment:
 
     ``forward`` is the mixed-batch jit table ({config -> jitted fn}) and is
     ``None`` when the engine runs the serialized iteration, in which case
-    ``prefill``/``decode`` carry the 2×2 table instead."""
+    ``prefill``/``decode`` carry the 2×2 table instead. ``built`` holds the
+    ``(rows, chunk)`` shapes either config's ``forward`` program has run,
+    for the engine or for a warm-up calling it directly."""
 
     base: Model
     shift: Model
@@ -87,6 +101,7 @@ class Deployment:
     # aliases the plain ``forward`` table so a no-draft step runs the
     # exact pre-spec compiled program.
     spec_forward: Optional[dict] = None
+    built: Set[Tuple[int, int]] = field(default_factory=set)
 
     # ------------------------------------------------------------ identity
     @property
@@ -124,8 +139,9 @@ class Deployment:
             # prefill/decode table: prefill chunks and decode rows share a
             # forward pass, so the policy prices the real iteration.
             self.forward = {
-                c: _jit_step(m.forward_fn(paged=True, kernel=kc),
-                             f"step_{c}") for c, m in models.items()}
+                c: _recorded(_jit_step(m.forward_fn(paged=True, kernel=kc),
+                                       f"step_{c}"), self.built)
+                for c, m in models.items()}
             self.spec_forward = {}
         else:
             pg = self.paged

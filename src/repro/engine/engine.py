@@ -1023,8 +1023,10 @@ class ShiftEngine:
         don't see the context)."""
         facts = {"ctx_tokens": ctx_tokens, "n_rows": n_rows,
                  "ctx_max": ctx_max, "spec_tokens": spec_tokens}
-        name = "base" if self._use_base(n_tokens, n_prefill, facts) \
-            else "shift"
+        use_base = self.policy.use_base(
+            n_tokens, n_prefill,
+            **{k: facts[k] for k in self._policy_ctx_kwargs})
+        name = "base" if use_base else "shift"
         # shift-decision audit: the chosen config AND exactly the facts the
         # policy saw, folded into this step's record by step() — a base<->
         # shift flip is explainable from the trace alone
@@ -1036,19 +1038,6 @@ class ShiftEngine:
         if spec_tokens:
             self._step_audit["spec_tokens"] = spec_tokens
         return name
-
-    def _use_base(self, n_tokens: int, n_prefill: int, facts: dict) -> bool:
-        return self.policy.use_base(
-            n_tokens, n_prefill,
-            **{k: facts[k] for k in self._policy_ctx_kwargs})
-
-    def _rows_use_base(self, rows, n_prefill: int) -> bool:
-        """The policy's choice for a launch of ``rows`` (no drafts)."""
-        ends = [off + ql for _, off, ql, _ in rows]
-        return self._use_base(
-            sum(ql for _, _, ql, _ in rows), n_prefill,
-            {"ctx_tokens": sum(ends), "n_rows": len(rows),
-             "ctx_max": max(ends), "spec_tokens": 0})
 
     def _log_step(self, n_prefill: int, n_decode: int, n_ready: int,
                   attn_ctx: int = 0):
@@ -1172,8 +1161,7 @@ class ShiftEngine:
                 self._log_step(0, 0, n_ready)
                 return False
             if n_prefill_tok and decode_rows and not drafts:
-                rows, decode_rows = self._ride(rows, decode_rows,
-                                               n_prefill_tok, C)
+                rows, decode_rows = self._ride(rows, decode_rows, C)
                 n_decode = len(decode_rows)
 
             attn_ctx = sum(off + ql for _, off, ql, _ in rows)
@@ -1195,7 +1183,6 @@ class ShiftEngine:
             # every slot's row: it costs about one read of the weights
             # whatever its rows, so it keeps one shape per config.
             full = not n_prefill_tok and not drafts
-            placed, Rb = self._place(rows, full)
             Cb = max(_pow2(max(ql for _, _, ql, _ in rows)),
                      max(model.lay.sp, 1))
             if n_prefill_tok:
@@ -1205,6 +1192,8 @@ class ShiftEngine:
                 # per config, so a warm-up can cover the shapes a window
                 # meets
                 Cb = max(Cb, _pow2(C))
+            placed, Rb = self._place(
+                rows, full, Cb if n_prefill_tok and not drafts else 0)
         with self.obs.span("engine.inputs"):
             self._refresh_block_tables()
             # every launch carries the whole table width: the kernel (and its
@@ -1331,44 +1320,58 @@ class ShiftEngine:
                     self.obs.inc("spec_rollback_blocks_total", rollback_blocks)
             return True
 
-    def _place(self, rows, full: bool = False):
+    def _place(self, rows, full: bool = False, chunk: int = 0):
         """Launch row of each batch row, and the launch's row count: pow2
         bucketed, per dp row segment under dp>1; ``full`` launches every
-        slot's row."""
+        slot's row. A launch that carries prefill chunks at chunk bucket
+        ``chunk`` takes the fewest rows that hold its own among those a
+        ``forward`` program has already run at that chunk, where there
+        are such: a bucket built mid-serving stalls every row for seconds,
+        while padded rows cost a share of one step, and decode rows may
+        ride in them (``_ride``)."""
         if self.dp > 1:
             per = [[] for _ in range(self.dp)]
             for e in rows:
                 per[self.kv.row_of(e[0].slot)].append(e)
-            seg = _pow2(self.slots_per_row if full
-                        else max(len(p) for p in per))
+            seg = self._built_rows(self.dp * _pow2(
+                self.slots_per_row if full
+                else max(len(p) for p in per)), chunk) // self.dp
             return ([(ri * seg + j, e) for ri, p in enumerate(per)
                      for j, e in enumerate(p)], self.dp * seg)
-        return (list(enumerate(rows)),
-                _pow2(self.cfg.max_slots if full else len(rows)))
+        return (list(enumerate(rows)), self._built_rows(
+            _pow2(self.cfg.max_slots if full else len(rows)), chunk))
 
-    def _ride(self, rows, decode_rows, n_prefill: int, chunk: int):
+    def _built_rows(self, rows: int, chunk: int) -> int:
+        """The fewest rows, at least ``rows`` and a multiple of dp, of a
+        launch the deployment has run at ``chunk``; ``rows`` where there is
+        none or ``chunk`` is 0."""
+        fit = [r for r, c in self.deploy.built if chunk and c == chunk
+               and r >= rows and r % self.dp == 0]
+        return min(fit, default=rows)
+
+    def _ride(self, rows, decode_rows, chunk: int):
         """Which ready decode rows ride in a launch that carries prefill
         chunks. Every row of that launch is padded to the chunk width, so
         a decode row there costs a chunk of compute, while a decode-only
         launch costs about one read of the weights whatever its rows. The
         decode rows all ride while the padded tokens they add stay within
-        ``DECODE_RIDE_TOKENS``, or where the policy would run them alone in
-        the same config as the launch (a second launch of one program
-        pays its fixed costs twice: on one chip the scan's pool copies
-        lead a step). Otherwise only those that fit the rows the prefill
-        rows pad anyway ride, and the next step launches the decode rows
-        alone: prefill in base, decode in shift. Returns the rows to launch
-        and the decode rows among them."""
+        ``DECODE_RIDE_TOKENS``, whatever configs the two launches would
+        run. Otherwise only those that fit the rows the prefill rows pad
+        anyway ride, and the next step launches the decode rows alone
+        (under the threshold policy: prefill in base, decode in shift).
+        Rows are counted in the launch's row bucket (``_place``), which may
+        be a larger bucket already built. Returns the rows to launch and
+        the decode rows among them."""
+        cb = _pow2(chunk)
         pre = [e for e in rows if e[0] not in decode_rows]
-        rb_pre = self._place(pre)[1]
-        if (self._place(rows)[1] - rb_pre) * _pow2(chunk) \
-                <= DECODE_RIDE_TOKENS \
-                or self._rows_use_base(rows, n_prefill) == self._rows_use_base(
-                    [e for e in rows if e[0] in decode_rows], 0):
+        rb_pre = self._place(pre, chunk=cb)[1]
+        if (self._place(rows, chunk=cb)[1] - rb_pre) * cb \
+                <= DECODE_RIDE_TOKENS:
             return rows, decode_rows
         keep = list(pre)
         for e in rows:
-            if e[0] in decode_rows and self._place(keep + [e])[1] == rb_pre:
+            if e[0] in decode_rows \
+                    and self._place(keep + [e], chunk=cb)[1] == rb_pre:
                 keep.append(e)
         riding = {e[0] for e in keep} & decode_rows
         self._decode_turn = True

@@ -310,24 +310,48 @@ def attn_decode(p, x, cache, lens, cfg, lay: Layout, *, window: int = 0,
 # head-major [num_blocks, Hkv_loc, bs, Dh] pool slice. The old materialized gather
 # (O(B·nmax·bs) per layer regardless of occupancy) survives only as the
 # reference oracle in ``kernels.ref`` (KernelConfig(attn_backend="gather")).
+#
+# Inside the layer scan the pool arrives stacked, ``[L, N, Hkv, bs, Dh]``
+# (the scan's carry), with the layer index ``layer``: the layer then writes
+# and reads its blocks in the flat ``[L*N, Hkv, bs, Dh]`` view at block ids
+# offset by ``layer * N``, so no step slices or restacks the pool.
+
+
+def _pool_view(cache, layer):
+    """(k, v, base): the pool as the flat block array the kernel indexes,
+    and the block id of this layer's null block in it (``layer * N`` in a
+    stacked pool; 0 in one layer's own pool, ``layer`` None). Merging the
+    two leading dims of the (bs, Dh)-tiled array is a bitcast."""
+    kc, vc = cache["k"], cache["v"]
+    if layer is None:
+        return kc, vc, 0
+    flat = lambda a: a.reshape((-1,) + a.shape[2:])  # noqa: E731
+    return flat(kc), flat(vc), layer * kc.shape[1]
+
+
+def _pool_back(cache, kc, vc):
+    """The written flat view, in ``cache``'s own (possibly stacked) shape."""
+    return {"k": kc.reshape(cache["k"].shape),
+            "v": vc.reshape(cache["v"].shape)}
 
 
 def paged_attn_mixed(p, x, cache, offsets, q_lens, block_tables, cfg,
-                     lay: Layout, kcfg=None):
+                     lay: Layout, kcfg=None, layer=None):
     """Ragged mixed prefill+decode against the paged pool. x: [B, S_loc, d]
     where each row carries ``q_lens[b]`` fresh tokens starting at cache
     position ``offsets[b]`` (decode rows have q_len == 1, prefill rows up
     to the chunk width, padding rows 0). Columns past ``q_lens`` scatter
     into the null block and their outputs are garbage-but-finite (the
     caller discards them). ``kcfg``: KernelConfig selecting the attention
-    backend. Returns (out [B, S_loc, d], cache)."""
+    backend; ``layer``: this layer's index in a stacked pool (see
+    ``_pool_view``). Returns (out [B, S_loc, d], cache)."""
     plan = get_plan(cfg, lay)
     q, k, v = _project_exchange(p, x, cfg, lay, plan)
     B, S = q.shape[:2]
     pos = offsets[:, None] + jnp.arange(S)[None, :]            # [B, S] global
     q, k = _qk_post(p, q, k, pos, cfg, True)
 
-    kc, vc = cache["k"], cache["v"]
+    kc, vc, base = _pool_view(cache, layer)
     bs = kc.shape[2]
     nmax = block_tables.shape[1]
     # ragged scatter: only the first q_lens[b] columns are real tokens; the
@@ -338,18 +362,19 @@ def paged_attn_mixed(p, x, cache, offsets, q_lens, block_tables, cfg,
     valid = (jnp.arange(S)[None, :] < q_lens[:, None]) & (pos // bs < nmax)
     blk = jnp.take_along_axis(block_tables,
                               jnp.minimum(pos // bs, nmax - 1), axis=1)
-    blk = jnp.where(valid, blk, 0)                              # [B, S]
-    # head-major pool: index (block, :, slot) takes k's [B, S, heads, Dh]
-    kc = kc.at[blk, :, pos % bs].set(k)
-    vc = vc.at[blk, :, pos % bs].set(v)
-    out = K.paged_ragged_attend(q, kc, vc, block_tables, q_lens,
+    blk = jnp.where(valid, blk, 0) + base                       # [B, S]
+    # head-major pool: index (block, head, slot) takes k's [B, S, heads, Dh]
+    heads = jnp.arange(kc.shape[1])[None, None, :]
+    kc = kc.at[blk[..., None], heads, (pos % bs)[..., None]].set(k)
+    vc = vc.at[blk[..., None], heads, (pos % bs)[..., None]].set(v)
+    out = K.paged_ragged_attend(q, kc, vc, block_tables + base, q_lens,
                                 offsets + q_lens,
                                 soft_cap=cfg.logits_soft_cap, kcfg=kcfg)
-    return _finish(p, out, plan, lay), {"k": kc, "v": vc}
+    return _finish(p, out, plan, lay), _pool_back(cache, kc, vc)
 
 
 def paged_attn_prefill(p, x, cache, offsets, block_tables, cfg, lay: Layout,
-                       kcfg=None):
+                       kcfg=None, layer=None):
     """Chunked prefill against the paged pool — the degenerate mixed call
     with ``q_lens == S`` for every row: all S columns are written (rows
     not in this chunk batch must be all-null so their scatter lands in the
@@ -360,15 +385,15 @@ def paged_attn_prefill(p, x, cache, offsets, block_tables, cfg, lay: Layout,
     S = x.shape[1] * max(lay.sp, 1)            # full chunk width after a2a
     q_lens = jnp.full(offsets.shape, S, jnp.int32)
     return paged_attn_mixed(p, x, cache, offsets, q_lens, block_tables, cfg,
-                            lay, kcfg=kcfg)
+                            lay, kcfg=kcfg, layer=layer)
 
 
 def paged_attn_decode(p, x, cache, lens, block_tables, cfg, lay: Layout,
-                      kcfg=None):
+                      kcfg=None, layer=None):
     """One-token decode against the paged pool — the C == 1 kernel call.
     x: [B_loc, d]; lens: [B] write positions; block_tables: [B, nmax]
-    (all-null rows for inactive slots scatter into the null block).
-    Returns (out [B_loc, d], cache)."""
+    (all-null rows for inactive slots scatter into the null block);
+    ``layer`` as in ``paged_attn_mixed``. Returns (out [B_loc, d], cache)."""
     plan = get_plan(cfg, lay)
     xs = x[None]                                               # batch-as-seq
     q, k, v = _project_exchange(p, xs, cfg, lay, plan)
@@ -379,17 +404,18 @@ def paged_attn_decode(p, x, cache, lens, block_tables, cfg, lay: Layout,
     pos = lens[:, None]                                        # [B,1]
     q, k = _qk_post(p, q, k, pos, cfg, True)
 
-    kc, vc = cache["k"], cache["v"]
+    kc, vc, base = _pool_view(cache, layer)
     bs = kc.shape[2]
-    blk = block_tables[jnp.arange(B), lens // bs]              # [B]
-    kc = kc.at[blk, :, lens % bs].set(k[:, 0])
-    vc = vc.at[blk, :, lens % bs].set(v[:, 0])
-    out = K.paged_ragged_attend(q, kc, vc, block_tables,
+    blk = block_tables[jnp.arange(B), lens // bs] + base       # [B]
+    heads = jnp.arange(kc.shape[1])[None, :]
+    kc = kc.at[blk[:, None], heads, (lens % bs)[:, None]].set(k[:, 0])
+    vc = vc.at[blk[:, None], heads, (lens % bs)[:, None]].set(v[:, 0])
+    out = K.paged_ragged_attend(q, kc, vc, block_tables + base,
                                 jnp.ones_like(lens), lens + 1,
                                 soft_cap=cfg.logits_soft_cap, kcfg=kcfg)
     out = out.transpose(1, 0, 2, 3)                            # [1,B,q_pr,dh]
     out = _finish(p, out, plan, lay)                           # [1,B_loc,d]
-    return out[0], {"k": kc, "v": vc}
+    return out[0], _pool_back(cache, kc, vc)
 
 
 # ---------------------------------------------------------------------------

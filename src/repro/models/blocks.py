@@ -142,11 +142,13 @@ def block_prefill(p, kind, x, cache, ctx, cfg, lay: Layout, pod_scale=False,
             a, cache = A.paged_attn_mixed(p["attn"], h, cache, offsets,
                                           ctx["q_lens"],
                                           ctx["block_tables"], cfg, lay,
-                                          kcfg=ctx.get("kcfg"))
+                                          kcfg=ctx.get("kcfg"),
+                                          layer=ctx.get("layer"))
         elif ctx.get("block_tables") is not None:
             a, cache = A.paged_attn_prefill(p["attn"], h, cache, offsets,
                                             ctx["block_tables"], cfg, lay,
-                                            kcfg=ctx.get("kcfg"))
+                                            kcfg=ctx.get("kcfg"),
+                                            layer=ctx.get("layer"))
         else:
             a, cache = A.attn_prefill(p["attn"], h, cache, offsets, cfg, lay)
         x = x + a
@@ -195,7 +197,8 @@ def block_decode(p, kind, x, cache, ctx, cfg, lay: Layout, pod_scale=False):
         elif ctx.get("block_tables") is not None:
             a, cache = A.paged_attn_decode(p["attn"], h, cache, lens,
                                            ctx["block_tables"], cfg, lay,
-                                           kcfg=ctx.get("kcfg"))
+                                           kcfg=ctx.get("kcfg"),
+                                           layer=ctx.get("layer"))
         else:
             a, cache = A.attn_decode(p["attn"], h, cache, lens, cfg, lay)
         x = x + a

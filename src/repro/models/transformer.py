@@ -1,7 +1,14 @@
 """Model assembly: stacks blocks per the config's layer pattern with
 ``lax.scan`` over pattern repeats (HLO stays O(1) in depth), builds caches,
 and exposes the three step bodies (train / prefill / decode) that run inside
-``shard_map``."""
+``shard_map``.
+
+A stacked paged pool ``[L, N, Hkv, bs, Dh]`` rides in the scan's carry with
+the layer index, and each layer addresses its blocks in the flat
+``[L*N, ...]`` view at block ids offset by ``layer * N`` (see
+``attention._pool_view``): the pool is written in place, never sliced per
+layer or restacked. Other caches (contiguous, MLA latents, ring buffers,
+recurrent state) are small per layer and stay the scan's ``xs``/``ys``."""
 from __future__ import annotations
 
 import math
@@ -215,6 +222,12 @@ def _run_encoder(params, frames, cfg, lay):
     return apply_norm(cfg.norm, params["enc_norm"], x, cfg.norm_eps)
 
 
+def _paged(cache, ctx) -> bool:
+    """Whether the step runs against the paged pool: then every cached
+    layer holds one (``Model.supports_paged``)."""
+    return cache is not None and ctx.get("block_tables") is not None
+
+
 def _run_blocks_prefill(params, cache, x, ctx, cfg, lay, pod_scale, train,
                         remat=False):
     kinds = cfg.layer_kinds
@@ -229,23 +242,31 @@ def _run_blocks_prefill(params, cache, x, ctx, cfg, lay, pod_scale, train,
         newc["prefix"][str(i)] = c
         aux += a
 
+    paged = _paged(cache, ctx)
+
     def sb(carry, xs):
-        xc, auxc = carry
+        # a paged pool rides in the carry, with the layer index ``i``;
+        # other caches are the scan's xs/ys
+        (xc, auxc), i, pools = carry
         pb, cb = xs
+        lctx, cb = (dict(ctx, layer=i), pools) if paged else (ctx, cb)
         out_cb = {}
         for si, kind in enumerate(cfg.layer_pattern):
             xc, c, a = BK.block_prefill(pb[f"s{si}"], kind, xc,
                                         cb[f"s{si}"] if cb is not None else None,
-                                        ctx, cfg, lay, pod_scale, train)
+                                        lctx, cfg, lay, pod_scale, train)
             out_cb[f"s{si}"] = c if c is not None else jnp.zeros((), jnp.int32)
             auxc = auxc + a
-        return (xc, auxc), out_cb
+        if paged:
+            return ((xc, auxc), i + 1, out_cb), None
+        return ((xc, auxc), i + 1, None), out_cb
 
     if reps:
         fn = jax.checkpoint(sb) if remat else sb
-        (x, aux), body_c = jax.lax.scan(
-            fn, (x, aux), (params["body"], cache["body"] if cache else None))
-        newc["body"] = body_c
+        ((x, aux), _, pools), body_c = jax.lax.scan(
+            fn, ((x, aux), jnp.int32(0), cache["body"] if paged else None),
+            (params["body"], cache["body"] if cache and not paged else None))
+        newc["body"] = pools if paged else body_c
     nsuf = len(cfg.suffix_layers)
     off = npre + reps * len(cfg.layer_pattern)
     for i in range(nsuf):
@@ -267,18 +288,26 @@ def _run_blocks_decode(params, cache, x, ctx, cfg, lay, pod_scale):
                                cache["prefix"][str(i)], ctx, cfg, lay, pod_scale)
         newc["prefix"][str(i)] = c
 
-    def sb(xc, xs):
+    paged = _paged(cache, ctx)
+
+    def sb(carry, xs):
+        xc, i, pools = carry                       # as in _run_blocks_prefill
         pb, cb = xs
+        lctx, cb = (dict(ctx, layer=i), pools) if paged else (ctx, cb)
         out_cb = {}
         for si, kind in enumerate(cfg.layer_pattern):
-            xc, c = BK.block_decode(pb[f"s{si}"], kind, xc, cb[f"s{si}"],
-                                    ctx, cfg, lay, pod_scale)
-            out_cb[f"s{si}"] = c
-        return xc, out_cb
+            xc, out_cb[f"s{si}"] = BK.block_decode(pb[f"s{si}"], kind, xc,
+                                                   cb[f"s{si}"], lctx, cfg,
+                                                   lay, pod_scale)
+        if paged:
+            return (xc, i + 1, out_cb), None
+        return (xc, i + 1, None), out_cb
 
     if reps:
-        x, body_c = jax.lax.scan(sb, x, (params["body"], cache["body"]))
-        newc["body"] = body_c
+        (x, _, pools), body_c = jax.lax.scan(
+            sb, (x, jnp.int32(0), cache["body"] if paged else None),
+            (params["body"], None if paged else cache["body"]))
+        newc["body"] = pools if paged else body_c
     nsuf = len(cfg.suffix_layers)
     off = npre + reps * len(cfg.layer_pattern)
     for i in range(nsuf):

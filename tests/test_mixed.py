@@ -232,9 +232,9 @@ def test_decode_rows_ride_where_their_padding_is_cheap(n_pre, n_dec, chunk,
     """Decode rows ride in a launch carrying prefill chunks while the
     padded tokens they add stay within ``DECODE_RIDE_TOKENS`` (1024), or
     in the rows the prefill rows pad anyway; the rest take the next step.
-    Under ThresholdPolicy(32) the launch runs base and 16 decode rows alone
-    would run shift; under ThresholdPolicy(4) both run base, and every
-    decode row rides."""
+    The configs do not enter: under ThresholdPolicy(32) the launch runs
+    base and the decode rows alone would run shift, under
+    ThresholdPolicy(4) both run base, and the same rows ride."""
     cfg = reduced_cfg("qwen3-8b")
     m = build_model(cfg, dtype=jnp.float32)
     params = m.init_params(jax.random.key(0))
@@ -248,25 +248,27 @@ def test_decode_rows_ride_where_their_padding_is_cheap(n_pre, n_dec, chunk,
     rows = ([(r, 0, chunk, False) for r in reqs[:n_pre]]
             + [(r, 3, 1, True) for r in reqs[n_pre:]])
     dec = set(reqs[n_pre:])
-    kept, rode = eng._ride(rows, dec, n_pre * chunk, chunk)
-    assert len(rode) == riding and rode <= dec
-    assert [e for e in kept if e[0] not in dec] == rows[:n_pre]
-    assert eng._decode_turn == (riding < n_dec)
-    eng._decode_turn = False
-    eng.policy = ThresholdPolicy(4)
-    assert eng._ride(rows, dec, n_pre * chunk, chunk) == (rows, dec)
-    assert not eng._decode_turn
+    for threshold in (32, 4):
+        eng.policy = ThresholdPolicy(threshold)
+        eng._decode_turn = False
+        kept, rode = eng._ride(rows, dec, chunk)
+        assert len(rode) == riding and rode <= dec
+        assert [e for e in kept if e[0] not in dec] == rows[:n_pre]
+        assert [e for e in kept if e[0] in dec] == [
+            e for e in rows if e[0] in rode]
+        assert eng._decode_turn == (riding < n_dec)
 
 
 def test_decode_rows_left_out_take_the_next_step(monkeypatch):
     """With no padded tokens allowed, a step carrying prefill chunks
     launches decode rows only in the rows its prefill rows pad anyway; the
     next step launches the decode rows alone, and the token streams stay
-    those of the serialized engine."""
+    those of the serialized engine. One prompt first, so that the burst's
+    two prefill rows find no built bucket larger than their own."""
     cfg = reduced_cfg("qwen3-8b")
     m = build_model(cfg, dtype=jnp.float32)
     params = m.init_params(jax.random.key(0))
-    prompts = [list(range(1, 12 + i)) for i in range(3)] + [list(range(2, 40))]
+    prompts = [list(range(2, 40))]
     burst = [list(range(3, 30)), list(range(5, 26))]
     monkeypatch.setattr(engine_mod, "DECODE_RIDE_TOKENS", 0)
     g_mix, e_mix = _run_engine(m, params, True, prompts, burst=list(burst))
@@ -281,6 +283,41 @@ def test_decode_rows_left_out_take_the_next_step(monkeypatch):
         assert not nxt["prefill_tokens"] and nxt["decode_tokens"]
         assert nxt["bucket"][:2] == [4, 1]
 
+
+
+@pytest.mark.parametrize("warmed,rows", [
+    ((), 1),                       # nothing built: the pow2 bucket
+    (((4, 8),), 4),                # a warm-up's 4 x 8 holds the one row
+    (((2, 8), (4, 8)), 2),         # the smallest built bucket that holds it
+    (((2, 1),), 1),                # a bucket at another chunk does not
+])
+def test_prefill_launch_takes_the_smallest_built_bucket(warmed, rows):
+    """A step that carries a prefill chunk launches in the fewest rows that
+    hold its own among the shapes a ``forward`` program has already run at
+    its chunk width, a warm-up's direct calls included; the tokens do not
+    change."""
+    cfg = reduced_cfg("qwen3-8b")
+    m = build_model(cfg, dtype=jnp.float32)
+    params = m.init_params(jax.random.key(0))
+    ecfg = EngineConfig(max_slots=4, s_max=64, prefill_chunk=8)
+    eng = ShiftEngine(m, m, params, params, ecfg, policy=ThresholdPolicy(4))
+    nmax = eng.kv.max_blocks_per_seq
+    for R, C in warmed:                # as a warm-up runs a bucket
+        z = [jnp.zeros((R, C), jnp.int32), jnp.zeros((R,), jnp.int32),
+             jnp.zeros((R,), jnp.int32), jnp.zeros((R, nmax), jnp.int32)]
+        _, eng.cache = eng.deploy.forward["base"](params, eng.cache, *z)
+    assert eng.deploy.built == set(warmed)
+    r = Request(0, list(range(1, 15)), max_new_tokens=3)
+    eng.add_request(r)
+    eng.run_until_idle()
+    recs = [x for x in eng.step_log if x["config"]]
+    assert recs[0]["prefill_tokens"] and recs[0]["bucket"][:2] == [rows, 8]
+    fresh = ShiftEngine(m, m, params, params, ecfg,
+                        policy=ThresholdPolicy(4))
+    ref = Request(0, list(range(1, 15)), max_new_tokens=3)
+    fresh.add_request(ref)
+    fresh.run_until_idle()
+    assert r.generated == ref.generated
 
 def test_policy_sees_combined_mixed_tokens():
     """ThresholdPolicy must be fed prefill + decode tokens of the fused

@@ -88,10 +88,10 @@ def _engine(policy):
     return spec, eng
 
 
-def _serve(policy):
-    """Serve three prompts; return the spec, the requests and, per launch,
-    (config, tokens, q_lens, offsets, logits of each row's newest
-    position)."""
+def _serve(policy, stagger=False):
+    """Serve three prompts (``stagger``: the first alone until it has a
+    token); return the spec, the requests and, per launch, (config,
+    tokens, q_lens, offsets, logits of each row's newest position)."""
     spec, eng = _engine(policy)
     launches = []
     for mode, fn in list(eng.deploy.forward.items()):
@@ -111,6 +111,9 @@ def _serve(policy):
             for i, n in enumerate((37, 22, 45))]
     for r in reqs:
         eng.add_request(r)
+        while stagger and not r.generated:
+            eng.step()
+        stagger = False
     eng.run_until_idle()
     assert all(len(r.generated) == 5 for r in reqs)
     return spec, eng, reqs, launches
@@ -126,10 +129,10 @@ def _reference_logits(spec, seqs):
         return np.asarray(ref.hidden(toks) @ ref.head())
 
 
-def _compare(policy):
+def _compare(policy, stagger=False):
     """Largest |program - reference| logit over every launched row, as a
     share of the reference logits' range, and the configs that ran."""
-    spec, eng, reqs, launches = _serve(policy)
+    spec, eng, reqs, launches = _serve(policy, stagger)
     seqs = [list(r.prompt) + list(r.generated) for r in reqs]
     ref = _reference_logits(spec, seqs)
     worst, served, configs = 0.0, set(), set()
@@ -173,11 +176,12 @@ def test_served_logits_match_the_float32_reference(name, policy, want):
 def test_decode_turns_match_the_float32_reference(monkeypatch):
     """With no padded tokens allowed to decode rows (``DECODE_RIDE_TOKENS``
     0), prefill steps run base with the decode rows left out and the next
-    steps decode them alone in shift: the logits still match. The
-    threshold is 8 tokens, so that the step where the shortest prompt has
-    turned to decode while two chunks remain (28 tokens) runs base."""
+    steps decode them alone in shift: the logits still match. The first
+    prompt is served alone until it decodes, so the other two prompts'
+    chunks (26 tokens, over the threshold of 8: base) find no built row
+    bucket with room for its decode row."""
     monkeypatch.setattr(engine_mod, "DECODE_RIDE_TOKENS", 0)
-    worst, configs, eng = _compare(ThresholdPolicy(8))
+    worst, configs, eng = _compare(ThresholdPolicy(8), stagger=True)
     assert configs == {"base", "shift"}
     assert worst < RTOL, worst
     recs = [r for r in eng.step_log if r["config"]]
